@@ -40,7 +40,7 @@ from typing import Iterable, List, Optional, Tuple
 
 import jax
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 # primitives whose output is a (possibly aliased) relayout of one operand
 VIEW_PRIMS = frozenset({"slice", "squeeze", "reshape", "transpose"})

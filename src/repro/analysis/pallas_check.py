@@ -1,13 +1,15 @@
 """Static Pallas kernel verifier: VMEM, tiling and dtype contracts.
 
 Every ``pl.pallas_call`` in ``src/repro/kernels/`` encodes hardware
-contracts that used to live in docstrings and bare asserts: the one-hot
-``(block_docs*Md, K)`` ADC tile "fits in VMEM for K <= 512", the corpus
-axis "must divide by block_docs", the output "is f32". This module
-checks them *statically* — no TPU, no Mosaic lowering — for every
-registered kernel geometry (``kernel_sites``: the manifest trace
-geometry, the serving-scale geometry, and the documented envelope), and
-for planted test fixtures.
+contracts that used to live in docstrings and bare asserts: the ADC
+one-hot tile "fits in VMEM for K <= 512", the corpus axis "must divide
+by block_docs", the output "is f32". This module checks them
+*statically* — no TPU, no Mosaic lowering — for every registered kernel
+geometry (``kernel_sites``: the manifest trace geometry, the
+serving-scale geometry, and the documented envelope), and for planted
+test fixtures. It does not check the TPU's (8, 128) block tiling, and it
+passed kernels the TPU compiler refused: ``tests/test_tpu_compile.py``
+compiles them for a described chip.
 
 Capture is two-pass and backend-free:
 
@@ -369,8 +371,9 @@ def _qmaxsim_site(name: str, *, b: int, mq: int, k: int, md: int,
         from repro.core.scan import _kernel_tile
         from repro.kernels import quantized_maxsim as qk
         tile = _kernel_tile(
-            block, 32,
-            fits=lambda t: vmem.fits(qk.qmaxsim_vmem_bytes(t, mq, k, md)))
+            block, 256,
+            lambda t: vmem.fits(qk.qmaxsim_vmem_bytes(t, mq, k, md)),
+            lane=True)
 
         def fn(table, qm, codes, dm):
             return qk.quantized_maxsim_pallas(table, qm, codes, dm,
@@ -389,7 +392,7 @@ def _maxsim_site(name: str, *, b: int, mq: int, md: int, d: int,
         from repro.kernels import maxsim as mk
         tile = _kernel_tile(
             block, 16,
-            fits=lambda t: vmem.fits(mk.maxsim_vmem_bytes(t, mq, md, d)))
+            lambda t: vmem.fits(mk.maxsim_vmem_bytes(t, mq, md, d)))
 
         def fn(q, qm, docs, dm):
             return mk.maxsim_pallas(q, qm, docs, dm, block_docs=tile)
@@ -406,8 +409,9 @@ def _hamming_site(name: str, *, b: int, mq: int, md: int,
         from repro.core.scan import _kernel_tile
         from repro.kernels import hamming as hk
         tile = _kernel_tile(
-            block, 64,
-            fits=lambda t: vmem.fits(hk.hamming_vmem_bytes(t, mq, md)))
+            block, 256,
+            lambda t: vmem.fits(hk.hamming_vmem_bytes(t, mq, md)),
+            lane=True)
 
         def fn(qc, qm, dc, dm):
             return hk.hamming_maxsim_pallas(qc, qm, dc, dm, bits=8,

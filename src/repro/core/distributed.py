@@ -19,12 +19,10 @@ wired into `Retriever.build(..., mesh=...)` via `sharded_kmeans_fit` /
 """
 from __future__ import annotations
 
-import warnings
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import late_interaction as li
@@ -41,8 +39,8 @@ def corpus_data_axes(mesh: Mesh, n: int) -> Tuple[str, ...]:
     (dist/sharding.py DEFAULT_RULES — one source of truth with
     `Retriever.shard`, so build-time and search-time sharding can't
     drift): missing axes are skipped and axes drop from the right until n
-    divides the shard product. Returns () when nothing divides (caller
-    falls back to the single-host path).
+    divides the shard product. Returns () when nothing divides (the
+    sharded build stages then raise).
     """
     entry = Sharder(mesh).resolve(("corpus",), (n,))[0]
     if entry is None:
@@ -117,11 +115,11 @@ def sharded_search_fn(mesh: Mesh, corpus_axes: Tuple[str, ...], *, k: int,
         g_i = jnp.take_along_axis(all_i, g_pos, axis=1)
         return g_s, g_i
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(P(), P(), corpus_spec, corpus_spec, corpus_spec, P()),
         out_specs=(P(), P()),
-        check_rep=False))
+        check_vma=False))
 
 
 def sharded_kmeans_refine_fn(mesh: Mesh, data_axes: Tuple[str, ...], *,
@@ -209,9 +207,9 @@ def sharded_kmeans_refine_fn(mesh: Mesh, data_axes: Tuple[str, ...], *,
         best_i = jnp.where(better, last_i, best_i)
         return best_c, inertias, best_i
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fit, mesh=mesh, in_specs=(x_spec, P()), out_specs=(P(), P(), P()),
-        check_rep=False))
+        check_vma=False))
 
 
 def sharded_kmeans_fit(mesh: Mesh, key: Array, x: Array,
@@ -223,11 +221,10 @@ def sharded_kmeans_fit(mesh: Mesh, key: Array, x: Array,
     Per restart: k-means++ seeding on the (replicated, O(seed_batch))
     subsample using the exact keys the single-host path derives, then the
     shard_map'd Lloyd v2 over x sharded on `data_axes`; the restart with
-    the lowest final inertia wins. Falls back to the single-host fit —
-    with a warning, since that re-introduces the full-device-memory build
-    the mesh was meant to avoid — when the mesh has none of the
-    ("pod", "data", "model") corpus axes or N doesn't divide the shard
-    product.
+    the lowest final inertia wins. Raises `ValueError` when the mesh has
+    none of the ("pod", "data", "model") corpus axes or N doesn't divide
+    the shard product: a one-device fallback would silently bring back
+    the full-device-memory build the mesh was meant to avoid.
 
     Stochastic mini-batch mode is single-host-only; here `config.minibatch`
     instead bounds the E-step's per-device transient to
@@ -244,13 +241,12 @@ def sharded_kmeans_fit(mesh: Mesh, key: Array, x: Array,
     if data_axes is None:
         data_axes = corpus_data_axes(mesh, n)
     if not data_axes:
-        warnings.warn(
+        raise ValueError(
             f"sharded_kmeans_fit: no 'corpus'-rule mesh axis divides "
             f"N={n} on mesh "
-            f"{dict(zip(mesh.axis_names, mesh.devices.shape))}; falling "
-            "back to the single-host fit (full single-device memory)",
-            stacklevel=2)
-        return quant.kmeans_fit(key, x, config)
+            f"{dict(zip(mesh.axis_names, mesh.devices.shape))}; a sharded "
+            "build cannot run on one device — pad the corpus to a multiple "
+            "of the shard count or build without a mesh")
     refine = sharded_kmeans_refine_fn(
         mesh, data_axes, k=config.k, iters=config.iters, n_total=n,
         block_rows=config.minibatch if config.minibatch > 0 else 65536)
@@ -273,17 +269,16 @@ def sharded_quantize(mesh: Mesh, x: Array, codebook: Array, code_dtype,
 
     Assignment inside the shard runs through `quantization.quantize`, which
     routes to the Pallas kernel (kernels/kmeans_assign.py) on TPU and the
-    reference jnp path elsewhere. Falls back to single-host quantization
-    when no corpus axis divides N.
+    reference jnp path elsewhere. Raises `ValueError` when no corpus axis
+    divides N.
     """
     n = x.shape[0]
     if data_axes is None:
         data_axes = corpus_data_axes(mesh, n)
     if not data_axes:
-        warnings.warn(
-            f"sharded_quantize: no corpus mesh axis divides N={n}; "
-            "falling back to single-host quantization", stacklevel=2)
-        return quant.quantize(x, codebook, code_dtype=code_dtype)
+        raise ValueError(
+            f"sharded_quantize: no corpus mesh axis divides N={n} on mesh "
+            f"{dict(zip(mesh.axis_names, mesh.devices.shape))}")
     in_spec = P(*((data_axes,) + (None,) * (x.ndim - 1)))
     out_spec = P(*((data_axes,) + (None,) * (x.ndim - 2)))
 
@@ -292,8 +287,8 @@ def sharded_quantize(mesh: Mesh, x: Array, codebook: Array, code_dtype,
         return quant.quantize(x_local, cb, code_dtype=code_dtype,
                               impl="auto")
 
-    fn = jax.jit(shard_map(f, mesh=mesh, in_specs=(in_spec, P()),
-                           out_specs=out_spec, check_rep=False))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(in_spec, P()),
+                           out_specs=out_spec, check_vma=False))
     x_sh = jax.device_put(x, NamedSharding(mesh, in_spec))
     return fn(x_sh, codebook)
 

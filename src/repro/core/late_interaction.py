@@ -55,8 +55,14 @@ def maxsim(q: Array, q_mask: Array, d: Array, d_mask: Array) -> Array:
 
 
 def adc_table(q: Array, codebook: Array) -> Array:
-    """Query-token x centroid similarity table T (B, Mq, K)."""
+    """Query-token x centroid similarity table T (B, Mq, K).
+
+    Full f32 precision: at the default precision the TPU rounds both
+    operands to bf16 (~1% error in T on the chip), and every ADC score
+    inherits T's error. The table is B*Mq*K*D MACs per batch — no cost.
+    """
     return jnp.einsum("bqd,kd->bqk", q, codebook,
+                      precision=jax.lax.Precision.HIGHEST,
                       preferred_element_type=jnp.float32)
 
 

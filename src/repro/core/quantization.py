@@ -78,10 +78,16 @@ def pairwise_sq_dists(x: Array, c: Array) -> Array:
     every downstream consumer that treats the output as a distance — the
     k-means++ categorical weights (log of a negative) and inertia /
     ``quantization_error`` sums. Argmin is unaffected by the clamp.
+
+    The matmul runs at full f32 precision, as the Pallas assignment kernel
+    does: at the default precision the TPU rounds x and c to bf16, which
+    moves near-tie assignments and lets the sharded and single-device
+    k-means drift apart.
     """
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)          # (N, 1)
     c2 = jnp.sum(c * c, axis=-1)                         # (K,)
-    xc = x @ c.T                                         # (N, K) — MXU
+    xc = jnp.matmul(x, c.T,
+                    precision=jax.lax.Precision.HIGHEST)  # (N, K) — MXU
     return jnp.maximum(x2 - 2.0 * xc + c2[None, :], 0.0)
 
 
@@ -309,10 +315,10 @@ def quantize(x: Array, centroids: Array, code_dtype=jnp.uint8, *,
     """Quantize embeddings (…, M, D) -> codes (…, M) of code_dtype.
 
     Works for arbitrary leading batch dims (vmapped assignment). `impl`
-    routes the assignment: the default "jnp" is the canonical form —
-    bit-stable and device-independent, so mesh-less builds reproduce
-    everywhere; "auto" uses the Pallas kernel on TPU and the canonical
-    form elsewhere (what the sharded build path passes); anything else is
+    routes the assignment: the default "jnp" is the canonical form;
+    "auto" uses the Pallas kernel on TPU and the canonical form elsewhere
+    (what every index build passes — on the chip the canonical form
+    would materialise an (N, K) distance matrix); anything else is
     forwarded to `repro.kernels.ops.kmeans_assign`
     ("pallas"/"interpret"/"ref").
     """

@@ -100,22 +100,28 @@ def score_sentinel(dtype) -> Array:
     return jnp.array(-jnp.inf, dtype)
 
 
-def _kernel_tile(t: int, default: int, fits=None) -> int:
-    """Inner Pallas doc tile for a t-doc block (VMEM-sized, divides t).
+def _kernel_tile(t: int, default: int, fits=None, *, lane: bool = False
+                 ) -> int:
+    """Inner Pallas doc tile for a t-doc block: the largest divisor of t,
+    at most ``default``, that the TPU compiler accepts and that fits.
 
-    ``fits(tile) -> bool`` is the kernel's VMEM predicate (its
-    ``*_vmem_bytes`` footprint vs ``kernels.vmem.VMEM_BUDGET_BYTES``).
-    The tile halves — staying a divisor of ``t`` — until it fits, so a
-    wide geometry (e.g. the ADC kernel's one-hot tile at K=512, Md=128)
-    gets a smaller doc tile instead of a Mosaic VMEM failure; if no
-    halving fits, the kernel's own ``ValueError`` surfaces the computed
-    footprint.
+    ``lane=True`` marks kernels whose doc tile is the lane dim of their
+    blocks (the ADC and hamming kernels score codes transposed), so a
+    tile must be a multiple of 128 or t itself; t itself is the answer
+    whenever t <= default. ``fits(tile) -> bool`` is the kernel's VMEM
+    predicate (its ``*_vmem_bytes`` footprint vs
+    ``kernels.vmem.VMEM_BUDGET_BYTES``): the largest legal tile that
+    fits wins; if none fits, the smallest legal one is returned and the
+    kernel's own ``ValueError`` surfaces the computed footprint.
     """
-    tile = default if (t > default and t % default == 0) else t
-    if fits is not None:
-        while tile > 1 and tile % 2 == 0 and not fits(tile):
-            tile //= 2
-    return tile
+    legal = [d for d in range(min(t, default), 0, -1)
+             if t % d == 0 and (not lane or d == t or d % vmem.LANES == 0)]
+    if not legal:                    # t > default, no 128-multiple divides
+        legal = [t]
+    for tile in legal:
+        if fits is None or fits(tile):
+            return tile
+    return legal[-1]
 
 
 # ---------------------------------------------------------------------------
@@ -268,7 +274,7 @@ def quantized_maxsim_topk(q: Array, q_mask: Array, codes: Array,
         if per_query:
             def score_block(c, m):
                 def one(tab, qm1, cc, mm):
-                    tile = _kernel_tile(cc.shape[0], 32, fits=qfits)
+                    tile = _kernel_tile(cc.shape[0], 256, qfits, lane=True)
                     return qmaxsim_k.quantized_maxsim_pallas(
                         tab[None], qm1[None], cc.astype(jnp.int32),
                         mm.astype(jnp.float32), block_docs=tile,
@@ -276,7 +282,7 @@ def quantized_maxsim_topk(q: Array, q_mask: Array, codes: Array,
                 return jax.vmap(one)(table, qm_f, c, m)
         else:
             def score_block(c, m):
-                tile = _kernel_tile(c.shape[0], 32, fits=qfits)
+                tile = _kernel_tile(c.shape[0], 256, qfits, lane=True)
                 return qmaxsim_k.quantized_maxsim_pallas(
                     table, qm_f, c.astype(jnp.int32), m.astype(jnp.float32),
                     block_docs=tile, interpret=interpret)
@@ -333,14 +339,14 @@ def maxsim_topk(q: Array, q_mask: Array, docs: Array, d_mask: Array, *,
         if per_query:
             def score_block(d, m):
                 def one(q1, qm1, d1, m1):
-                    tile = _kernel_tile(d1.shape[0], 16, fits=mfits)
+                    tile = _kernel_tile(d1.shape[0], 16, mfits)
                     return maxsim_k.maxsim_pallas(
                         q1[None], qm1[None], d1, m1.astype(jnp.float32),
                         block_docs=tile, interpret=interpret)[0]
                 return jax.vmap(one)(q, qm_f, d, m)
         else:
             def score_block(d, m):
-                tile = _kernel_tile(d.shape[0], 16, fits=mfits)
+                tile = _kernel_tile(d.shape[0], 16, mfits)
                 return maxsim_k.maxsim_pallas(q, qm_f, d,
                                               m.astype(jnp.float32),
                                               block_docs=tile,
@@ -405,7 +411,7 @@ def hamming_maxsim_topk(q_codes: Array, q_mask: Array, d_codes: Array,
         if per_query:
             def score_block(d, m):
                 def one(q1, qm1, d1, m1):
-                    tile = _kernel_tile(d1.shape[0], 64, fits=hfits)
+                    tile = _kernel_tile(d1.shape[0], 256, hfits, lane=True)
                     return hamming_k.hamming_maxsim_pallas(
                         q1[None], qm1[None], d1.astype(jnp.int32),
                         m1.astype(jnp.float32), bits=bits,
@@ -414,7 +420,7 @@ def hamming_maxsim_topk(q_codes: Array, q_mask: Array, d_codes: Array,
                 return jnp.maximum(out, float(ii.min)).astype(jnp.int32)
         else:
             def score_block(d, m):
-                tile = _kernel_tile(d.shape[0], 64, fits=hfits)
+                tile = _kernel_tile(d.shape[0], 256, hfits, lane=True)
                 out = hamming_k.hamming_maxsim_pallas(
                     q_codes, qm_f, d.astype(jnp.int32), m.astype(jnp.float32),
                     bits=bits, block_docs=tile, interpret=interpret)

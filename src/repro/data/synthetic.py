@@ -9,6 +9,8 @@ well-defined:
     ColPali-Full, PQ-Only, HPC and DistilCol are meaningful (stands in for
     ViDoRe / SEC-Filings; two presets differ in topic count, patch count
     and noise to mimic the two datasets' difficulty gap);
+  * `make_page_chunk` / `make_page_queries` — the same page model made on
+    the device chunk by chunk, for corpora too large to hold as floats;
   * `make_fact_corpus` — RAG corpus where each document carries an explicit
     fact set; hallucination (generated fact not present in retrieved
     context) is *exactly* measurable;
@@ -21,6 +23,7 @@ well-defined:
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, NamedTuple, Tuple
 
 import jax
@@ -139,6 +142,62 @@ def make_retrieval_corpus(key: Array, spec: CorpusSpec) -> RetrievalData:
                          sal.astype(jnp.float32), topic.astype(jnp.int32),
                          q_patches.astype(jnp.float32), q_mask,
                          q_sal.astype(jnp.float32), rel)
+
+
+def make_topic_banks(key: Array, spec: CorpusSpec) -> Array:
+    """Patch prototypes (n_topics, patches_per_topic, D), drawn as in
+    `make_retrieval_corpus`: a topic centre plus per-prototype spread."""
+    k_c, k_p = jax.random.split(key)
+    centers = jax.random.normal(k_c, (spec.n_topics, spec.dim))
+    return centers[:, None, :] + 0.7 * jax.random.normal(
+        k_p, (spec.n_topics, spec.patches_per_topic, spec.dim))
+
+
+@partial(jax.jit, static_argnames=("spec", "n"))
+def make_page_chunk(key: Array, banks: Array, spec: CorpusSpec, n: int
+                    ) -> Tuple[Array, Array, Array]:
+    """n pages for corpora built chunk by chunk (no (Q, N) relevance).
+
+    A page draws its salient patches from its own topic's prototypes and
+    its background from one other topic's, so it holds a few dozen
+    distinct patch clusters, as a rendered page does. Generated on the
+    device in one program; returns (patches (n, Md, D) f32 unit-norm,
+    mask (n, Md) bool, salience (n, Md) f32).
+    """
+    md, d = spec.n_patches, spec.dim
+    t, p = spec.n_topics, spec.patches_per_topic
+    n_sal = max(1, int(md * spec.salient_frac))
+    ks = jax.random.split(key, 7)
+    topic = jax.random.randint(ks[0], (n, 1), 0, t)
+    bg_topic = jax.random.randint(ks[1], (n, 1), 0, t)
+    proto = jnp.concatenate([
+        topic * p + jax.random.randint(ks[2], (n, n_sal), 0, p),
+        bg_topic * p + jax.random.randint(ks[3], (n, md - n_sal), 0, p)],
+        axis=1)                                            # (n, Md)
+    patches = banks.reshape(t * p, d)[proto]
+    patches = patches + spec.noise * jax.random.normal(ks[4], patches.shape)
+    patches = patches / jnp.linalg.norm(patches, axis=-1, keepdims=True)
+    sal = jnp.concatenate([
+        0.8 + 0.2 * jax.random.uniform(ks[5], (n, n_sal)),
+        0.2 * jax.random.uniform(ks[6], (n, md - n_sal))], axis=1)
+    return patches, jnp.ones((n, md), bool), sal
+
+
+@partial(jax.jit, static_argnames=("spec",))
+def make_page_queries(key: Array, patches: Array, targets: Array,
+                      spec: CorpusSpec) -> Tuple[Array, Array, Array]:
+    """Queries built from the salient patches of `targets` pages (rows of
+    `patches`) plus noise, as in `make_retrieval_corpus`. Returns
+    (embeddings (Q, Mq, D), mask (Q, Mq) bool, salience (Q, Mq))."""
+    n_sal = max(1, int(spec.n_patches * spec.salient_frac))
+    q, mq = targets.shape[0], spec.n_q_patches
+    k_pick, k_noise, k_sal = jax.random.split(key, 3)
+    pick = jax.random.randint(k_pick, (q, mq), 0, n_sal)
+    emb = patches[targets[:, None], pick]
+    emb = emb + spec.noise * jax.random.normal(k_noise, emb.shape)
+    emb = emb / jnp.linalg.norm(emb, axis=-1, keepdims=True)
+    sal = 0.5 + 0.5 * jax.random.uniform(k_sal, (q, mq))
+    return emb, jnp.ones((q, mq), bool), sal
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -40,7 +39,7 @@ def ring_allgather_matmul(mesh: Mesh, axis_name: str):
         # after i hops, device d holds w block (d + i) % n
         shift_left = [((j + 1) % n, j) for j in range(n)]
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P(axis_name, None), P(None, axis_name)),
                  out_specs=P(axis_name, None))
         def run(x_blk, w_blk):
@@ -54,7 +53,10 @@ def ring_allgather_matmul(mesh: Mesh, axis_name: str):
                 w_cur = jax.lax.ppermute(w_cur, axis_name, shift_left)
                 return out, w_cur
 
-            out0 = jnp.zeros((x_blk.shape[0], p), out_dtype)
+            # the carry varies over the axis after one step; type it so
+            # from the start (shard_map's varying-manual-axes check)
+            out0 = jax.lax.pcast(jnp.zeros((x_blk.shape[0], p), out_dtype),
+                                 axis_name, to="varying")
             out, _ = jax.lax.fori_loop(0, n, step, (out0, w_blk))
             return out
 

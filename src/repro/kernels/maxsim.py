@@ -2,20 +2,19 @@
 
 score(b, n) = sum_i q_mask[b,i] * max_j (d_mask[n,j] ? <q[b,i], d[n,j]> : -inf)
 
-Tiling (docs/design.md §7): the query block for batch row b — (Mq, D) — stays
-resident in VMEM across the whole corpus sweep; documents stream through in
-blocks of `block_docs` docs ((block_docs*Md, D) flattened so the Q @ D^T is a
-single MXU matmul per tile). Per-tile VMEM:
+Tiling (docs/design.md §7): the query block for batch row b — (Mq, D) —
+is resident in VMEM; documents stream through in tiles of `block_docs`
+docs, (block_docs, Md, D). Per doc the kernel runs one (Mq, D) x (D, Md)
+MXU matmul at full f32 precision, masks and max-reduces over Md, and
+drops the query-masked sum into lane t of a (1, block_docs) score row.
+At Md=616, D=128 a doc is 315 KB of f32, so the tile stays small (16
+docs ≈ 10 MiB double-buffered); the score row is therefore written as a
+whole (1, block_docs) block of a (N // block_docs, B, 1, block_docs)
+output, which the TPU compiler accepts for any tile, and transposed back
+to (B, N) outside.
 
-    Mq*D*4  +  block_docs*Md*D*4  +  Mq*block_docs*Md*4 (sims)  +  out
-
-e.g. Mq=32, Md=64, D=128, block_docs=16 -> 16 KB + 512 KB + 128 KB ≈ 0.7 MB,
-comfortably inside the ~16 MB v5e VMEM with double buffering. MXU alignment:
-choose Mq, block_docs*Md multiples of 128 where possible (ops.py pads).
-
-Grid: (B, N // block_docs); the doc axis is the fastest-varying so the Q
-block is reused N/block_docs times per HBM read (grid iteration order on
-TPU is minor-to-major: last grid dim innermost).
+Grid: (N // block_docs, B), queries innermost, so each doc tile is read
+from HBM once per batch.
 """
 from __future__ import annotations
 
@@ -33,34 +32,38 @@ NEG_INF = -1e30
 
 def maxsim_vmem_bytes(block_docs: int, mq: int, md: int, d: int) -> int:
     """Per-grid-step VMEM footprint of ``_maxsim_kernel`` in bytes:
-    double-buffered blocks + the (Mq, block_docs*Md) similarity
-    temporaries (raw + masked) and per-query reductions."""
-    blocks = 4 * (mq * d + mq + block_docs * md * d + block_docs * md
-                  + block_docs)
-    sims = 4 * (2 * mq * block_docs * md + 2 * mq * block_docs)
-    return vmem.DOUBLE_BUFFER * blocks + sims
+    double-buffered blocks + the per-doc (Mq, Md) similarity
+    temporaries (raw + masked) and the score row."""
+    tb = vmem.tile_bytes
+    blocks = (tb((mq, d), 4) + tb((mq, 1), 4) + tb((block_docs, md, d), 4)
+              + tb((block_docs, 1, md), 4) + tb((1, block_docs), 4))
+    temps = 2 * tb((mq, md), 4) + 2 * tb((1, block_docs), 4)
+    return vmem.DOUBLE_BUFFER * blocks + temps
 
 
 def _maxsim_kernel(q_ref, qm_ref, d_ref, dm_ref, out_ref):
     # q_ref:  (1, Mq, D)        VMEM
-    # qm_ref: (1, Mq)           VMEM
-    # d_ref:  (block_docs, Md, D)
-    # dm_ref: (block_docs, Md)
-    # out_ref: (1, block_docs)
+    # qm_ref: (1, Mq, 1)        query-token mask, a column
+    # d_ref:  (T, Md, D)
+    # dm_ref: (T, 1, Md)        patch mask, one row per doc
+    # out_ref: (1, 1, 1, T)
     q = q_ref[0].astype(jnp.float32)                      # (Mq, D)
-    d = d_ref[...].astype(jnp.float32)                    # (T, Md, D)
-    t, md, dd = d.shape
-    d_flat = d.reshape(t * md, dd)
-    # One MXU matmul per tile.
-    sim = jax.lax.dot_general(q, d_flat,
-                              (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    sim = sim.reshape(q.shape[0], t, md)                  # (Mq, T, Md)
-    dm = dm_ref[...]                                      # (T, Md) f32 0/1
-    sim = jnp.where(dm[None] > 0, sim, NEG_INF)
-    per_q = jnp.max(sim, axis=-1)                         # (Mq, T)
-    qm = qm_ref[0]                                        # (Mq,)
-    out_ref[0, :] = jnp.sum(per_q * qm[:, None], axis=0)
+    qm = qm_ref[0]                                        # (Mq, 1)
+    t = d_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+
+    def doc(i, row):
+        sim = jax.lax.dot_general(q, d_ref[i].astype(jnp.float32),
+                                  (((1,), (1,)), ((), ())),
+                                  precision=jax.lax.Precision.HIGHEST,
+                                  preferred_element_type=jnp.float32)
+        sim = jnp.where(dm_ref[i] > 0, sim, NEG_INF)      # (Mq, Md)
+        per_q = jnp.max(sim, axis=1, keepdims=True)       # (Mq, 1)
+        s = jnp.sum(per_q * qm, axis=0, keepdims=True)    # (1, 1)
+        return jnp.where(lane == i, s, row)
+
+    out_ref[0, 0] = jax.lax.fori_loop(0, t, doc,
+                                      jnp.zeros((1, t), jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("block_docs", "interpret"))
@@ -75,24 +78,26 @@ def maxsim_pallas(q, q_mask, docs, d_mask, *, block_docs: int = 16,
         maxsim_vmem_bytes(block_docs, mq, md, dd),
         kernel="maxsim_pallas",
         detail=f"block_docs={block_docs}, Mq={mq}, Md={md}, D={dd}; the "
-               f"doc block is ({block_docs * md}, {dd}) f32")
-    grid = (b, n // block_docs)
-    return pl.pallas_call(
+               f"doc block is ({block_docs}, {md}, {dd}) f32")
+    nb = n // block_docs
+    out = pl.pallas_call(
         _maxsim_kernel,
-        grid=grid,
+        grid=(nb, b),
         in_specs=[
-            pl.BlockSpec((1, mq, dd), lambda i, j: (i, 0, 0),
+            pl.BlockSpec((1, mq, dd), lambda j, i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, mq), lambda i, j: (i, 0),
+            pl.BlockSpec((1, mq, 1), lambda j, i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_docs, md, dd), lambda i, j: (j, 0, 0),
+            pl.BlockSpec((block_docs, md, dd), lambda j, i: (j, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((block_docs, md), lambda i, j: (j, 0),
+            pl.BlockSpec((block_docs, 1, md), lambda j, i: (j, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, block_docs), lambda i, j: (i, j),
+        out_specs=pl.BlockSpec((1, 1, 1, block_docs),
+                               lambda j, i: (j, i, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nb, b, 1, block_docs), jnp.float32),
         interpret=interpret,
-    )(q.astype(jnp.float32), q_mask.astype(jnp.float32),
-      docs.astype(jnp.float32), d_mask.astype(jnp.float32))
+    )(q.astype(jnp.float32), q_mask.astype(jnp.float32)[:, :, None],
+      docs.astype(jnp.float32), d_mask.astype(jnp.float32)[:, None, :])
+    return jnp.moveaxis(out[:, :, 0, :], 1, 0).reshape(b, n)

@@ -72,7 +72,7 @@ def maxsim(q: Array, q_mask: Array, docs: Array, d_mask: Array, *,
 @functools.partial(jax.jit, static_argnames=("impl", "block_docs"))
 def quantized_maxsim(q: Array, q_mask: Array, codes: Array, d_mask: Array,
                      codebook: Array, *, impl: Impl = "auto",
-                     block_docs: int = 32) -> Array:
+                     block_docs: int = 256) -> Array:
     """Fused ADC MaxSim scores (B, N) over a quantized corpus."""
     mode = _resolve(impl)
     qm = q_mask.astype(jnp.float32)
@@ -94,7 +94,7 @@ def quantized_maxsim(q: Array, q_mask: Array, codes: Array, d_mask: Array,
 @functools.partial(jax.jit, static_argnames=("bits", "impl", "block_docs"))
 def hamming_maxsim(q_codes: Array, q_mask: Array, d_codes: Array,
                    d_mask: Array, *, bits: int, impl: Impl = "auto",
-                   block_docs: int = 64) -> Array:
+                   block_docs: int = 256) -> Array:
     """Binary-mode MaxSim scores (B, N)."""
     mode = _resolve(impl)
     qm = q_mask.astype(jnp.float32)

@@ -26,11 +26,9 @@ from typing import Any, Dict
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/repro_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
-
 from repro.configs import registry
 from repro.launch import cells as cells_mod
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import (HBM_BW, HBM_BYTES, ICI_BW, PEAK_FLOPS_BF16,
                                make_production_mesh)
 
@@ -283,6 +281,7 @@ def main(argv=None) -> int:
             print(f"{arch_id:28s} {cell.name:16s} {cell.kind:10s}{flag}")
         return 0
 
+    enable_compile_cache()
     todo = []
     meshes = (["single", "multi"] if args.mesh == "both" else [args.mesh])
     if args.all:

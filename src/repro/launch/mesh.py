@@ -12,18 +12,31 @@ while tests/benchmarks see the real single CPU device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis `AxisType.Auto`.
+
+    The repo's sharding rules (`dist/sharding.py`) and its
+    `with_sharding_constraint` calls are written for Auto axes; JAX's
+    own default is Explicit, under which gathers on sharded state need
+    an explicit `out_sharding`. Every mesh the program builds goes
+    through here.
+    """
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape=(1, 1), axes=("data", "model")) -> Mesh:
     """Small mesh over whatever host devices exist (tests)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 # TPU v5e hardware constants (roofline §Roofline)

@@ -283,9 +283,11 @@ def encode_corpus(key: Array, corpus: Corpus, cfg: HPCConfig, mesh=None
     Splits the key exactly like v0 `build_index` (codebook key first, the
     remainder free for the backend's own structure, e.g. IVF routing),
     trains the codebook, quantizes the full corpus (the rerank structure),
-    and applies doc-side pruning for the primary structure. With a `mesh`,
-    codebook training and corpus quantization run sharded over the mesh's
-    corpus axes (assignment through the Pallas kernel on TPU devices).
+    and applies doc-side pruning for the primary structure. Assignment runs
+    through the Pallas kernel on TPU devices (no (N*Md, K) distance
+    matrix) and the canonical jnp form elsewhere. With a `mesh`, codebook
+    training and corpus quantization run sharded over the mesh's corpus
+    axes.
 
     Returns (struct_key, codebook, codes_full, codes, mask).
     """
@@ -297,7 +299,8 @@ def encode_corpus(key: Array, corpus: Corpus, cfg: HPCConfig, mesh=None
                                            code_dtype(cfg.k))       # (N, Md)
     else:
         codes_full = quant.quantize(corpus.embeddings, codebook,
-                                    code_dtype=code_dtype(cfg.k))   # (N, Md)
+                                    code_dtype=code_dtype(cfg.k),
+                                    impl="auto")                    # (N, Md)
     if cfg.prune_side in ("doc", "both"):
         codes, _, mask, _ = pruning.prune_topp_codes(
             codes_full, corpus.salience, corpus.mask, p=cfg.p)
@@ -319,7 +322,7 @@ def encode_delta(codebook: Array, delta: Corpus, cfg: HPCConfig
     """
     k = codebook.shape[0]
     codes_full = quant.quantize(delta.embeddings, codebook,
-                                code_dtype=code_dtype(k))
+                                code_dtype=code_dtype(k), impl="auto")
     if cfg.prune_side in ("doc", "both"):
         codes, _, mask, _ = pruning.prune_topp_codes(
             codes_full, delta.salience, delta.mask, p=cfg.p)
@@ -339,6 +342,10 @@ class IndexBackend:
     # True -> the backend's scores are exact late-interaction scores over
     # raw embeddings; the facade skips the quantized rerank stage.
     exact_scores: bool = False
+    # True -> `search` is an exhaustive scan of the corpus axis: run on a
+    # corpus shard it returns that shard's exact top-k under global ids,
+    # which is what `Retriever.search_sharded` merges.
+    exhaustive: bool = False
 
     # -- required -----------------------------------------------------------
 

@@ -23,6 +23,7 @@ Array = jax.Array
 
 @register_backend("flat")
 class FlatBackend(IndexBackend):
+    exhaustive = True
 
     def build(self, key: Array, corpus: Corpus, cfg: HPCConfig,
               mesh=None) -> RetrieverState:

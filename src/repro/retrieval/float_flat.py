@@ -21,6 +21,7 @@ Array = jax.Array
 
 @register_backend("float_flat")
 class FloatFlatBackend(IndexBackend):
+    exhaustive = True
     exact_scores = True
 
     def build(self, key: Array, corpus: Corpus, cfg: HPCConfig,

@@ -41,6 +41,7 @@ class HammingState:
 
 @register_backend("hamming")
 class HammingBackend(IndexBackend):
+    exhaustive = True
 
     def build(self, key: Array, corpus: Corpus, cfg: HPCConfig,
               mesh=None) -> RetrieverState:

@@ -14,7 +14,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import pruning
 from repro.core import scan as scan_mod
@@ -59,6 +59,19 @@ class Retriever:
 
     # -- online -------------------------------------------------------------
 
+    def _prune_query(self, query: Query) -> Query:
+        """Step 2 — query-side dynamic pruning (a no-op for doc-side)."""
+        if self.cfg.prune_side in ("query", "both"):
+            pr = pruning.prune_topp(query.embeddings, query.salience,
+                                    query.mask, p=self.cfg.p)
+            return Query(pr.embeddings, pr.mask, query.salience)
+        return query
+
+    def _n_cand(self, k: int) -> int:
+        """Candidates the backend returns: k, or the rerank depth if
+        deeper."""
+        return k if self.cfg.rerank == 0 else max(k, self.cfg.rerank)
+
     def search(self, state: RetrieverState, query: Query, *, k: int
                ) -> Tuple[Array, Array]:
         """Online query (paper §III-E2 steps 2-5).
@@ -66,25 +79,79 @@ class Retriever:
         Returns (scores (B, k), doc_ids (B, k)).
         """
         cfg, backend = self.cfg, self.backend
-
-        # Step 2 — query-side dynamic pruning.
-        q_emb, q_mask = query.embeddings, query.mask
-        if cfg.prune_side in ("query", "both"):
-            pr = pruning.prune_topp(q_emb, query.salience, q_mask, p=cfg.p)
-            q_emb, q_mask = pr.embeddings, pr.mask
-        pruned = Query(q_emb, q_mask, query.salience)
+        pruned = self._prune_query(query)
 
         # Steps 3-4 — backend candidate search (over-fetch for rerank).
         # All backends take the full v1 signature with `scan=` — legacy
         # out-of-tree backends get a kwargs-stripping shim at registration
         # (base.register_backend), so no signature sniffing here.
-        n_cand = k if cfg.rerank == 0 else max(k, cfg.rerank)
-        scores, ids = backend.search(state, pruned, k=n_cand, scan=cfg.scan)
+        scores, ids = backend.search(state, pruned, k=self._n_cand(k),
+                                     scan=cfg.scan)
 
         # Step 5 — rerank candidates with unpruned quantized MaxSim.
         if cfg.rerank and not backend.exact_scores:
             return self._rerank(state, pruned, scores, ids, k=k)
         return scores[:, :k], ids[:, :k]
+
+    def search_sharded(self, state: RetrieverState, query: Query, *, k: int,
+                       mesh: Mesh) -> Tuple[Array, Array]:
+        """`search` over a state placed on `mesh` by `shard`.
+
+        Each device scans only the documents it holds and keeps its own
+        top candidates; the candidates merge over the mesh (an all-gather
+        of (B, k) rows and a top-k), and the rerank scores each merged
+        candidate on the device that holds its unpruned row. The whole
+        search runs under `jax.shard_map`: the TPU compiler cannot split
+        a Pallas kernel across devices by itself. Returns what `search`
+        returns for the same state on one device, up to the order of
+        equal scores. Needs a backend whose `search` is an exhaustive
+        scan (`IndexBackend.exhaustive`).
+        """
+        cfg, backend = self.cfg, self.backend
+        if not backend.exhaustive:
+            raise NotImplementedError(
+                f"backend {backend.name!r} routes queries through its own "
+                "structure; search_sharded needs an exhaustive scan "
+                "(flat, float_flat, hamming)")
+        shd = Sharder(mesh)
+        specs = jax.tree.map(
+            lambda spec, leaf: shd.resolve(tuple(spec), jnp.shape(leaf)),
+            backend.shard_specs(state), state, is_leaf=is_logical_spec)
+        entry = specs.rerank_codes[0]
+        if entry is None:
+            raise ValueError(
+                "state is not sharded over the corpus on this mesh; use "
+                "search")
+        axes = (entry,) if isinstance(entry, str) else tuple(entry)
+        n_cand = self._n_cand(k)
+        rerank = bool(cfg.rerank) and not backend.exact_scores
+
+        def merge(scores, ids, kk):
+            for ax in axes:
+                scores = jax.lax.all_gather(scores, ax, axis=1, tiled=True)
+                ids = jax.lax.all_gather(ids, ax, axis=1, tiled=True)
+            # every device contributes kk rows, so kk <= the gathered width
+            top, pos = jax.lax.top_k(scores, kk)  # noqa: JAX04
+            return top, jnp.take_along_axis(ids, pos, axis=1)
+
+        def local(st, q):
+            pruned = self._prune_query(q)
+            scores, ids = merge(*backend.search(st, pruned, k=n_cand,
+                                                scan=cfg.scan), n_cand)
+            if not rerank:
+                return scores[:, :k], ids[:, :k]
+            rows = st.rerank_codes.shape[0]
+            row = ids - jax.lax.axis_index(axes) * rows
+            mine = (ids >= 0) & (row >= 0) & (row < rows)
+            row = jnp.clip(row, 0, rows - 1)
+            return merge(*scan_mod.quantized_maxsim_topk(
+                pruned.embeddings, pruned.mask, st.rerank_codes[row],
+                st.rerank_mask[row], st.codebook, k=k, doc_ids=ids,
+                valid=mine, scan=cfg.scan), k)
+
+        return jax.shard_map(local, mesh=mesh, in_specs=(specs, P()),
+                             out_specs=(P(), P()),
+                             check_vma=False)(state, query)
 
     def degrade_rungs(self, state: RetrieverState, *, k: int) -> Tuple:
         """Overload degradation rungs for serving (docs/design.md §11).
@@ -106,11 +173,7 @@ class Retriever:
         point of stepping down is shedding compute.
         """
         cfg, backend = self.cfg, self.backend
-        q_emb, q_mask = query.embeddings, query.mask
-        if cfg.prune_side in ("query", "both"):
-            pr = pruning.prune_topp(q_emb, query.salience, q_mask, p=cfg.p)
-            q_emb, q_mask = pr.embeddings, pr.mask
-        pruned = Query(q_emb, q_mask, query.salience)
+        pruned = self._prune_query(query)
         scores, ids = backend.search_degraded(state, pruned, k=k, rung=rung,
                                               scan=cfg.scan)
         return scores[:, :k], ids[:, :k]
@@ -188,7 +251,7 @@ class Retriever:
         axis resolves over ("pod", "data", "model") with the usual
         divisibility fallback (repro/dist/sharding.py), so the same index
         shards on any mesh that divides the document count and replicates
-        gracefully otherwise.
+        otherwise. Search the placed state with `search_sharded`.
         """
         shd = sharder if sharder is not None else Sharder(mesh)
         specs = self.backend.shard_specs(state)

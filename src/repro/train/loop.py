@@ -27,7 +27,6 @@ from typing import Any, Callable, Dict, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -179,9 +178,9 @@ def make_pipelined_fn(mesh: Mesh, stage_fn: Callable, n_microbatches: int,
             return out.reshape(x.shape)
 
         spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(spec_params, P()), out_specs=P(),
-            check_rep=False)(stage_params, x)
+            check_vma=False)(stage_params, x)
 
     return pipelined

@@ -300,7 +300,8 @@ def test_cascade_load_rejects_other_backend_file(data, tmp_path):
 
 def test_cascade_shard_places_state_and_preserves_results(data):
     cfg = _cfg("cascade", cascade=CascadeConfig(p1=24, p2=8))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = Retriever(cfg)
     state = r.build(jax.random.PRNGKey(9), _corpus(data))
     s0, i0 = r.search(state, _queries(data), k=5)
@@ -315,7 +316,8 @@ def test_cascade_shard_places_state_and_preserves_results(data):
 
 def test_cascade_build_on_1dev_mesh_matches_single_host(data):
     cfg = _cfg("cascade", cascade=CascadeConfig(p1=24, p2=8))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = Retriever(cfg)
     st_mesh = r.build(jax.random.PRNGKey(9), _corpus(data), mesh=mesh)
     st_local = r.build(jax.random.PRNGKey(9), _corpus(data))

@@ -12,7 +12,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import distributed as D, late_interaction as li, quantization as quant
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 ks = jax.random.split(key, 4)
 N, Md, Mq, B, dim, K = 64, 6, 4, 3, 16, 16
@@ -48,7 +49,8 @@ def test_sharded_kmeans_matches_local():
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import distributed as D, quantization as quant
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (512, 8))
 cfg = quant.KMeansConfig(k=16, iters=10, n_restarts=2)
@@ -73,7 +75,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.data import synthetic
 from repro.retrieval import Corpus, HPCConfig, Query, Retriever
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 spec = synthetic.CorpusSpec(n_docs=128, n_queries=8, n_patches=8,
                             n_q_patches=4, dim=16, n_topics=4)
 data = synthetic.make_retrieval_corpus(jax.random.PRNGKey(1), spec)
@@ -101,7 +104,8 @@ def test_ring_allgather_matmul():
 import jax, jax.numpy as jnp, numpy as np
 from repro.dist.collectives import ring_allgather_matmul
 
-mesh = jax.make_mesh((4,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("model",))
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (16, 8))
 w = jax.random.normal(jax.random.PRNGKey(1), (8, 12))
@@ -119,7 +123,8 @@ def test_pipeline_parallel_matches_sequential():
 import jax, jax.numpy as jnp, numpy as np
 from repro.train.loop import make_pipelined_fn
 
-mesh = jax.make_mesh((4,), ("pipe",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("pipe",))
 key = jax.random.PRNGKey(0)
 n_stages, mb, n_micro, d = 4, 4, 8, 16
 ws = jax.random.normal(key, (n_stages, d, d)) / jnp.sqrt(d)
@@ -152,7 +157,8 @@ tree = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
 specs = {{"w": ("batch", "mlp")}}
 ck.save("{tmp_path}", 3, tree)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 got = elastic.restore_elastic("{tmp_path}", jax.tree.map(jnp.zeros_like, tree), specs, mesh)
 step, restored = got
 assert step == 3
@@ -175,7 +181,8 @@ from repro.configs import registry
 from repro.launch import cells as cm
 import numpy as np
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 for arch, shape in [("qwen2-1.5b", "train_4k"), ("llama4-scout-17b-a16e", "decode_32k"),
                     ("pna", "molecule"), ("dlrm-mlperf", "serve_p99"),
                     ("dien", "retrieval_cand"), ("colpali-hpc", "serve_query")]:
@@ -208,7 +215,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models import layers as L
 from repro.dist.sharding import Sharder, NULL
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 sharder = Sharder(mesh)
 key = jax.random.PRNGKey(0)
 T, D, E, K, F = 64, 16, 8, 2, 24
@@ -238,3 +246,41 @@ for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_sh)):
 print("GROUPED_MOE_EP_OK")
 """)
     assert "GROUPED_MOE_EP_OK" in out
+
+
+@pytest.mark.slow
+def test_search_sharded_matches_one_device():
+    """Retriever.search_sharded over a state split across 4 devices (each
+    scans its own pages, candidates merge over the mesh, the rerank runs
+    where each row lives) returns what search returns on one device."""
+    out = run_subprocess("""
+import jax, numpy as np
+from repro.data import synthetic
+from repro.launch.mesh import make_mesh
+from repro.retrieval import Corpus, HPCConfig, Query, Retriever
+
+mesh = make_mesh((4,), ("data",))
+spec = synthetic.CorpusSpec(n_docs=128, n_queries=8, n_patches=8,
+                            n_q_patches=4, dim=16, n_topics=4)
+data = synthetic.make_retrieval_corpus(jax.random.PRNGKey(1), spec)
+corpus = Corpus(data.doc_patches, data.doc_mask, data.doc_salience)
+q = Query(data.query_patches, data.query_mask, data.query_salience)
+for backend, rerank in [("flat", 12), ("flat", 0), ("float_flat", 0),
+                        ("hamming", 12)]:
+    r = Retriever(HPCConfig(k=32, backend=backend, kmeans_iters=5,
+                            kmeans_restarts=1, rerank=rerank))
+    st = r.build(jax.random.PRNGKey(2), corpus)
+    s1, i1 = r.search(st, q, k=5)
+    sh = r.shard(st, mesh)
+    assert len(sh.rerank_codes.addressable_shards) == 4
+    s4, i4 = jax.jit(lambda s, qq: r.search_sharded(s, qq, k=5,
+                                                    mesh=mesh))(sh, q)
+    np.testing.assert_allclose(np.asarray(s4), np.asarray(s1), atol=1e-5,
+                               rtol=1e-6)
+    # equal scores may come back in another order; every id's score holds
+    for a, b, s in zip(np.asarray(i4), np.asarray(i1), np.asarray(s1)):
+        for j in np.flatnonzero(a != b):
+            assert np.sum(s == s[j]) >= 2, (backend, a, b, s)
+print("SEARCH_SHARDED_OK")
+""", n_devices=4)
+    assert "SEARCH_SHARDED_OK" in out

@@ -132,7 +132,8 @@ def test_save_load_roundtrip(data, hnsw_state, tmp_path):
 
 def test_shard_places_state_and_preserves_results(data, hnsw_state):
     r, state = hnsw_state
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     s0, i0 = r.search(state, _queries(data), k=K)
     sharded = r.shard(state, mesh)
     for leaf in jax.tree.leaves(sharded):

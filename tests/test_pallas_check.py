@@ -48,12 +48,13 @@ def test_capture_sees_blockspecs_and_kernel_temporaries():
     assert call.path.endswith("src/repro/kernels/quantized_maxsim.py")
     assert call.kernel_name == "_qmaxsim_kernel"
     assert call.grid and all(g >= 1 for g in call.grid)
-    # the one-hot (block_docs*Md, K) f32 expansion alone: the jaxpr pass
-    # must see at least that much in-kernel VMEM (the part BlockSpecs
-    # cannot)
-    tile = call.in_blocks[2].block_shape[0]
-    md, k = call.in_blocks[2].block_shape[1], call.in_blocks[0].block_shape[2]
-    assert call.kernel_tmp_bytes >= tile * md * k * 4
+    # codes are scored transposed, (Md, tile): the per-patch one-hot is a
+    # (K, tile) bf16 expansion — the jaxpr pass must see at least that
+    # much in-kernel VMEM (the part BlockSpecs cannot)
+    md, tile = call.in_blocks[2].block_shape
+    k = call.in_blocks[0].block_shape[2]
+    assert call.kernel_tmp_bytes >= k * tile * 2
+    assert tile % vmem.LANES == 0 or tile == call.in_blocks[2].array_shape[1]
     assert call.vmem_bytes() <= vmem.VMEM_BUDGET_BYTES
 
 
@@ -181,28 +182,32 @@ def test_planted_over_vmem_blockspec_rejected_in_registry_shape():
 # --- the runtime contract: ValueErrors with computed footprints -----------
 
 def test_qmaxsim_k512_default_tile_overflows_and_raises():
-    """The docstring's old claim ("K <= 512 keeps the one-hot tile in
-    VMEM") is false at the default 32-doc tile with Md=128 — the entry
-    point must now say so instead of silently spilling."""
-    need = qk.qmaxsim_vmem_bytes(32, 32, 512, 128)
+    """At K=512 a doc tile of 4096 lanes (Md=128) needs more VMEM than
+    the budget — the entry point must say so instead of silently
+    spilling."""
+    need = qk.qmaxsim_vmem_bytes(4096, 32, 512, 128)
     assert need > vmem.VMEM_BUDGET_BYTES
 
     def call():
         return qk.quantized_maxsim_pallas(
             jnp.zeros((8, 32, 512)), jnp.ones((8, 32)),
-            jnp.zeros((256, 128), jnp.int32), jnp.ones((256, 128)),
-            block_docs=32)
+            jnp.zeros((4096, 128), jnp.int32), jnp.ones((4096, 128)),
+            block_docs=4096)
     with pytest.raises(ValueError, match="VMEM footprint") as ei:
         jax.eval_shape(call)
-    assert "one-hot tile is (4096, 512)" in str(ei.value)
+    assert "one-hot tile is (512, 4096)" in str(ei.value)
 
 
 def test_scan_tile_picker_shrinks_k512_to_fit():
     from repro.core.scan import _kernel_tile
     fits = lambda t: vmem.fits(qk.qmaxsim_vmem_bytes(t, 32, 512, 128))
-    tile = _kernel_tile(256, 32, fits=fits)
-    assert tile == 16
+    tile = _kernel_tile(4096, 4096, fits, lane=True)
+    assert tile == 2048
     assert vmem.fits(qk.qmaxsim_vmem_bytes(tile, 32, 512, 128))
+    # only lane-legal tiles: multiples of 128, or the whole block
+    assert _kernel_tile(256, 256, fits, lane=True) == 256
+    assert _kernel_tile(100, 256, fits, lane=True) == 100
+    assert _kernel_tile(384, 256, fits, lane=True) == 128
     # and the static verifier agrees: the k512 registry site is clean
     site = next(s for s in kernel_sites() if s.name == "qmaxsim_k512")
     assert check_site(site) == []

@@ -183,7 +183,8 @@ def test_sharded_kmeans_parity_on_1dev_mesh():
     """Satellite: the sharded trainer on a 1-device mesh must reproduce
     the single-host codebook (same seeds, psum over one shard)."""
     from repro.core import distributed as dist
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (256, 8))
     cfg = quant.KMeansConfig(k=16, iters=8, n_restarts=2)
     c_sh, hist_sh = dist.sharded_kmeans_fit(mesh, jax.random.PRNGKey(3), x,

@@ -331,7 +331,8 @@ def test_load_rejects_wrong_backend(data, tmp_path):
 @pytest.mark.parametrize("name", list(CONFIGS))
 def test_shard_places_state_and_preserves_results(data, name):
     cfg = CONFIGS[name]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = Retriever(cfg)
     state = r.build(jax.random.PRNGKey(5), _corpus(data))
     s0, i0 = r.search(state, _queries(data), k=5)
@@ -351,7 +352,8 @@ def test_build_on_1dev_mesh_matches_single_host(data, name):
     distributed k-means, quantization shard-mapped) must reproduce the
     single-host codebook within tolerance and the same search answers."""
     cfg = CONFIGS[name]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = Retriever(cfg)
     st_mesh = r.build(jax.random.PRNGKey(5), _corpus(data), mesh=mesh)
     st_local = r.build(jax.random.PRNGKey(5), _corpus(data))

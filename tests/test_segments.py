@@ -15,7 +15,8 @@ Contract under test (docs/design.md §9):
     ids *within an equal-score tie group* — scores stay bit-identical.
     All other backends preserve scan order under compaction
     (`gather_live_rows` keeps slot order) and are bit-exact on both
-    scores and ids.
+    scores and ids — except the cascade's float-rerank scores, which
+    agree to ULP tolerance.
   * Segmented states round-trip through `save`/`load` (format v2);
     future-versioned files and non-index files fail with clear errors.
   * Accounting: `build_stats` reports live vs tombstoned docs,
@@ -38,6 +39,10 @@ BACKENDS = ["flat", "float_flat", "hamming", "ivf", "hnsw", "cascade"]
 # permute; every other backend folds segments in scan order and is
 # bit-exact on ids too.
 BITEXACT_IDS = {"flat", "float_flat", "hamming", "hnsw", "cascade"}
+# the cascade's last stage is a float MaxSim over gathered candidate rows,
+# whose Mq-sum XLA may reassociate between the segmented and compacted
+# programs: its scores agree to ULP tolerance, not bit for bit
+ULP_SCORES = {"cascade"}
 
 SPEC = synthetic.CorpusSpec(n_docs=240, n_queries=32, n_patches=8,
                             n_q_patches=4, dim=32, n_topics=6,
@@ -164,7 +169,11 @@ def test_compact_bit_consistency(churned):
     backend = churned["backend"]
     s0, s1 = churned["scores"], churned["scores_compact"]
     i0, i1 = churned["ids"], churned["ids_compact"]
-    assert np.array_equal(s0, s1), backend
+    if backend in ULP_SCORES:
+        np.testing.assert_allclose(s1, s0, atol=1e-5, rtol=1e-6,
+                                   err_msg=backend)
+    else:
+        assert np.array_equal(s0, s1), backend
     if backend in BITEXACT_IDS:
         assert np.array_equal(i0, i1), backend
     else:
