@@ -1,21 +1,17 @@
-"""Mesh-sharded retrieval: the paper's system at production scale.
+"""Mesh-sharded index building: the paper's system at production scale.
 
-The quantized corpus (codes + masks + ids) is sharded over mesh axes
-(each device owns N/n_dev documents); queries are replicated. Each device
-runs the fused ADC MaxSim scan over its shard, takes a *local* top-k, and
-the global answer is the top-k of the all-gathered (score, id) pairs —
-k <= 128, so the merge traffic is k * 8 bytes vs the multi-GB scan, i.e.
-negligible (quantified in EXPERIMENTS.md §Roofline for the colpali cells).
-
-Also contains the sharded K-Means v2 trainer: points sharded over devices,
-replicated codebook, per-cluster sums reduced with psum, empty-cluster
-repair via a local-top-k/all-gather/global-top-k farthest-point merge, and
-multi-restart select-best — the same algorithm as the single-host
-`quantization.kmeans_fit` (seeding reuses its `seed_centroids`, so on a
-1-device mesh the two paths agree within float tolerance). This is the
-streaming-codebook building block the paper lists as future work (§VII),
-wired into `Retriever.build(..., mesh=...)` via `sharded_kmeans_fit` /
-`sharded_quantize`.
+The search over a corpus sharded across the mesh is
+`Retriever.search_sharded` (each device scans its own pages, the (B, k)
+candidates all-gather into a global top-k). This module holds what the
+sharded build needs: the K-Means v2 trainer with points sharded over
+devices, a replicated codebook, per-cluster sums reduced with psum,
+empty-cluster repair via a local-top-k/all-gather/global-top-k
+farthest-point merge, and multi-restart select-best — the same algorithm
+as the single-host `quantization.kmeans_fit` (seeding reuses its
+`seed_centroids`, so on a 1-device mesh the two paths agree within float
+tolerance). This is the streaming-codebook building block the paper lists
+as future work (§VII), wired into `Retriever.build(..., mesh=...)` via
+`sharded_kmeans_fit` / `sharded_quantize`.
 """
 from __future__ import annotations
 
@@ -25,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import late_interaction as li
 from repro.core import quantization as quant
 from repro.dist.sharding import Sharder
 
@@ -46,80 +41,6 @@ def corpus_data_axes(mesh: Mesh, n: int) -> Tuple[str, ...]:
     if entry is None:
         return ()
     return (entry,) if isinstance(entry, str) else tuple(entry)
-
-
-def sharded_search_fn(mesh: Mesh, corpus_axes: Tuple[str, ...], *, k: int,
-                      block_docs: int = 128):
-    """Build a shard_map'd corpus-sharded ADC search function.
-
-    Args:
-      mesh: the device mesh.
-      corpus_axes: mesh axes the document dimension is sharded over
-        (e.g. ("data", "model") for 256-way on a single pod).
-      k: global top-k.
-      block_docs: local scan block — bounds the transient (B, Mq, blk, Md)
-        similarity buffer exactly like the Pallas kernel's doc tile
-        (§Perf iteration colpali-1: 79.6 GiB/dev -> fits; on TPU this jnp
-        block loop is replaced by kernels/quantized_maxsim.py).
-      k: global top-k.
-
-    Returns a function
-      (q (B, Mq, D), q_mask (B, Mq),
-       codes (N, Md), mask (N, Md), doc_ids (N,), codebook (K, D))
-      -> (scores (B, k), ids (B, k))
-    with codes/mask/doc_ids sharded over corpus_axes on dim 0 and everything
-    else replicated.
-    """
-    corpus_spec = P(corpus_axes)
-    n_shards = 1
-    for a in corpus_axes:
-        n_shards *= mesh.shape[a]
-
-    def local_search(q, q_mask, codes, mask, doc_ids, codebook):
-        # Local fused scan over this device's shard, in doc blocks so the
-        # (B, Mq, blk, Md) sim tile stays VMEM-sized (kernel semantics).
-        n_local, md = codes.shape
-        q_mask = q_mask.astype(jnp.float32)
-        blk = min(block_docs, n_local)
-        while n_local % blk != 0:
-            blk //= 2
-        table = li.adc_table(q, codebook)                      # (B, Mq, K)
-
-        def score_block(c_blk):
-            codes_b, mask_b = c_blk
-            sim = jnp.take(table, codes_b.astype(jnp.int32).reshape(-1),
-                           axis=2)
-            sim = sim.reshape(*table.shape[:2], blk, md)       # (B,Mq,blk,Md)
-            sim = jnp.where(mask_b[None, None] > 0, sim, li.NEG_INF)
-            per_q = jnp.max(sim, axis=-1)                      # (B, Mq, blk)
-            per_q = per_q * q_mask[:, :, None]
-            return jnp.sum(per_q, axis=1)                      # (B, blk)
-
-        blocks = (codes.reshape(-1, blk, md),
-                  mask.reshape(-1, blk, md).astype(jnp.float32))
-        scores = jax.lax.map(score_block, blocks)              # (nb, B, blk)
-        scores = jnp.moveaxis(scores, 0, 1).reshape(q.shape[0], n_local)
-        local_k = min(k, codes.shape[0])
-        # JAX04-safe: local_k = min(k, shard size) just above
-        top_s, top_i = jax.lax.top_k(scores, local_k)  # noqa: JAX04
-        top_ids = doc_ids[top_i]
-        # Global merge: gather every shard's candidates, re-top-k.
-        all_s = top_s
-        all_i = top_ids
-        for ax in corpus_axes:
-            all_s = jax.lax.all_gather(all_s, ax, axis=1, tiled=True)
-            all_i = jax.lax.all_gather(all_i, ax, axis=1, tiled=True)
-        # JAX04-safe: callers cap k at the global corpus size, and the
-        # gathered axis holds local_k * n_shards >= min(k, N) entries
-        g_s, g_pos = jax.lax.top_k(all_s, k)  # noqa: JAX04
-        g_i = jnp.take_along_axis(all_i, g_pos, axis=1)
-        return g_s, g_i
-
-    return jax.jit(jax.shard_map(
-        local_search, mesh=mesh,
-        in_specs=(P(), P(), corpus_spec, corpus_spec, corpus_spec, P()),
-        out_specs=(P(), P()),
-        check_vma=False))
 
 
 def sharded_kmeans_refine_fn(mesh: Mesh, data_axes: Tuple[str, ...], *,
@@ -291,10 +212,3 @@ def sharded_quantize(mesh: Mesh, x: Array, codebook: Array, code_dtype,
                            out_specs=out_spec, check_vma=False))
     x_sh = jax.device_put(x, NamedSharding(mesh, in_spec))
     return fn(x_sh, codebook)
-
-
-def corpus_shardings(mesh: Mesh, corpus_axes: Tuple[str, ...]):
-    """NamedShardings for (codes, mask, doc_ids, codebook, queries...)."""
-    c = NamedSharding(mesh, P(corpus_axes))
-    r = NamedSharding(mesh, P())
-    return dict(codes=c, mask=c, doc_ids=c, codebook=r, replicated=r)

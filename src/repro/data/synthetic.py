@@ -59,6 +59,7 @@ class CorpusSpec:
     noise: float = 0.25        # patch noise (higher -> harder corpus)
     salient_frac: float = 0.5  # fraction of patches that carry signal
     dup_per_doc: int = 3       # graded-relevant near-duplicates per query
+    page_protos: int = 8       # make_page_chunk: prototypes of a page
 
 
 # Presets standing in for the paper's two datasets: ViDoRe (academic pages,
@@ -158,22 +159,26 @@ def make_page_chunk(key: Array, banks: Array, spec: CorpusSpec, n: int
                     ) -> Tuple[Array, Array, Array]:
     """n pages for corpora built chunk by chunk (no (Q, N) relevance).
 
-    A page draws its salient patches from its own topic's prototypes and
-    its background from one other topic's, so it holds a few dozen
-    distinct patch clusters, as a rendered page does. Generated on the
-    device in one program; returns (patches (n, Md, D) f32 unit-norm,
-    mask (n, Md) bool, salience (n, Md) f32).
+    A page holds its own `page_protos` salient and `page_protos`
+    background prototypes, each drawn from the whole bank, so its set of
+    patch clusters (and of codes, once quantized) is its own: pages do
+    not share one ADC score by the thousand, as they would if a page's
+    clusters were fixed by its topic. Generated on the device in one
+    program; returns (patches (n, Md, D) f32 unit-norm, mask (n, Md)
+    bool, salience (n, Md) f32).
     """
     md, d = spec.n_patches, spec.dim
-    t, p = spec.n_topics, spec.patches_per_topic
+    t, p, s = spec.n_topics, spec.patches_per_topic, spec.page_protos
     n_sal = max(1, int(md * spec.salient_frac))
     ks = jax.random.split(key, 7)
-    topic = jax.random.randint(ks[0], (n, 1), 0, t)
-    bg_topic = jax.random.randint(ks[1], (n, 1), 0, t)
+    own_sal = jax.random.randint(ks[0], (n, s), 0, t * p)
+    own_bg = jax.random.randint(ks[1], (n, s), 0, t * p)
     proto = jnp.concatenate([
-        topic * p + jax.random.randint(ks[2], (n, n_sal), 0, p),
-        bg_topic * p + jax.random.randint(ks[3], (n, md - n_sal), 0, p)],
-        axis=1)                                            # (n, Md)
+        jnp.take_along_axis(
+            own_sal, jax.random.randint(ks[2], (n, n_sal), 0, s), axis=1),
+        jnp.take_along_axis(
+            own_bg, jax.random.randint(ks[3], (n, md - n_sal), 0, s),
+            axis=1)], axis=1)                              # (n, Md)
     patches = banks.reshape(t * p, d)[proto]
     patches = patches + spec.noise * jax.random.normal(ks[4], patches.shape)
     patches = patches / jnp.linalg.norm(patches, axis=-1, keepdims=True)

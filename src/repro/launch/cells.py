@@ -23,13 +23,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.configs.base import ArchSpec, ShapeCell
-from repro.core import distributed as dist_core
 from repro.dist.sharding import Sharder, is_logical_spec
 from repro.models import colpali as colpali_mod
 from repro.models import gnn as gnn_mod
 from repro.models import recsys as recsys_mod
 from repro.models import transformer as T
 from repro.optim import optimizer as opt
+from repro.retrieval import Query, Retriever
 
 SDS = jax.ShapeDtypeStruct
 
@@ -323,24 +323,26 @@ def build_colpali_cell(spec: ArchSpec, cell: ShapeCell, mesh: Mesh,
         return BuiltCell(spec.arch_id, cell, fn, (params_sds, pat, msk),
                          (p_sh, pat_sh, msk_sh), None, (), meta)
 
-    # search: sharded ADC MaxSim scan over the quantized corpus
-    corpus_axes = tuple(mesh.axis_names)     # flat over all axes
+    # search: the corpus-sharded ADC MaxSim scan + global top-k merge, as
+    # `Retriever.search_sharded` serves it (no rerank: scan + merge only)
     q_n, n_docs = dims["queries"], dims["corpus"]
-    md, mq = arch.kept_patches, enc.query_len
-    search = dist_core.sharded_search_fn(mesh, corpus_axes, k=arch.top_k)
-    q = SDS((q_n, mq, enc.proj_dim), jnp.float32)
-    qm = SDS((q_n, mq), jnp.float32)
-    codes = SDS((n_docs, md), jnp.int32)
-    dm = SDS((n_docs, md), jnp.float32)
-    ids = SDS((n_docs,), jnp.int32)
-    cb = SDS((arch.hpc.k, enc.proj_dim), jnp.float32)
-    # ADC scan reads 4 B/code (int32 lanes); table build is the only matmul
-    meta = {"model_flops": 2.0 * q_n * mq * arch.hpc.k * enc.proj_dim
+    md, mq, d = arch.kept_patches, enc.query_len, enc.proj_dim
+    retriever = Retriever(dataclasses.replace(arch.hpc, rerank=0))
+    state = retriever.backend.abstract_state(n=n_docs, md=md, d=d,
+                                             k=arch.hpc.k)
+    st_sh = _shard_tree(sharder, retriever.backend.shard_specs(state), state)
+    query = Query(SDS((q_n, mq, d), jnp.float32), SDS((q_n, mq), jnp.bool_),
+                  SDS((q_n, mq), jnp.float32))
+    q_sh = jax.tree.map(lambda s: sharder.named((None,) * len(s.shape),
+                                                s.shape), query)
+    fn = lambda st, q: retriever.search_sharded(st, q, k=arch.top_k,
+                                                mesh=mesh)
+    # ADC scan reads 1 B/code; table build is the only matmul
+    meta = {"model_flops": 2.0 * q_n * mq * arch.hpc.k * d
             + 1.0 * q_n * mq * n_docs * md,   # compares (add/max ops)
-            "params": arch.hpc.k * enc.proj_dim}
-    return BuiltCell(spec.arch_id, cell, search,
-                     (q, qm, codes, dm, ids, cb),
-                     None, None, (), meta)
+            "params": arch.hpc.k * d}
+    return BuiltCell(spec.arch_id, cell, fn, (state, query), (st_sh, q_sh),
+                     None, (), meta)
 
 
 FAMILY_BUILDERS = {

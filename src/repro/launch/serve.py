@@ -43,6 +43,9 @@ from repro.serving.client import drive
 from repro.serving.server import AsyncRetrievalServer, ServeConfig
 
 
+PAGE_CHUNK = 4096    # pages generated and indexed per step (2 GB of f32)
+
+
 def page_spec(arch) -> synthetic.CorpusSpec:
     """The synthetic page model at a deployment's encoder widths."""
     enc = arch.encoder
@@ -51,25 +54,26 @@ def page_spec(arch) -> synthetic.CorpusSpec:
 
 
 def build_pages(retriever: Retriever, key, spec: synthetic.CorpusSpec,
-                n_pages: int, chunk: int, n_queries: int, *,
+                n_pages: int, n_queries: int, *,
                 budget_s=None, min_pages: int = 0):
-    """Index `n_pages` seeded pages, `chunk` pages at a time.
+    """Index `n_pages` seeded pages, `PAGE_CHUNK` pages at a time.
 
     `Retriever.build` on the first chunk (which also fits the codebook),
     `Retriever.add` for every further chunk, one `Retriever.compact` to
-    fold the segments. Queries are drawn from the first chunk's pages
-    (ids 0..chunk-1). Once `budget_s` seconds have passed and at least
-    `min_pages` are in, the build stops early.
+    fold the segments. Queries are drawn from the first chunk's pages.
+    Once `budget_s` seconds have passed and at least `min_pages` are in,
+    the build stops early.
 
     Returns (state, queries, target ids, pages indexed, stop reason).
     """
+    chunk = min(PAGE_CHUNK, n_pages)
     k_bank, k_build, k_query, k_chunks = jax.random.split(key, 4)
     banks = synthetic.make_topic_banks(k_bank, spec)
     t0 = time.perf_counter()
     first = synthetic.make_page_chunk(jax.random.fold_in(k_chunks, 0), banks,
                                       spec, chunk)
     targets = jnp.arange(n_queries, dtype=jnp.int32) * max(
-        1, chunk // n_queries)
+        1, chunk // n_queries) % chunk
     queries = Query(*synthetic.make_page_queries(k_query, first[0], targets,
                                                  spec))
     state = retriever.build(k_build, Corpus(*first))
@@ -80,10 +84,11 @@ def build_pages(retriever: Retriever, key, spec: synthetic.CorpusSpec,
                 and time.perf_counter() - t0 > budget_s):
             reason = f"build budget of {budget_s:.0f} s spent"
             break
+        size = min(chunk, n_pages - pages)
         delta = synthetic.make_page_chunk(
-            jax.random.fold_in(k_chunks, pages // chunk), banks, spec, chunk)
+            jax.random.fold_in(k_chunks, pages // chunk), banks, spec, size)
         state = retriever.add(state, Corpus(*delta))
-        pages += chunk
+        pages += size
     if pages > chunk:
         state = retriever.compact(state)
     jax.block_until_ready(state)
@@ -135,8 +140,6 @@ def main(argv=None):
                     help="serve a deployment at its configured widths")
     ap.add_argument("--pages", type=int, default=65536,
                     help="--arch: pages to index")
-    ap.add_argument("--chunk", type=int, default=4096,
-                    help="--arch: pages generated and added per step")
     ap.add_argument("--n-docs", type=int, default=4096)
     ap.add_argument("--queries", type=int, default=256)
     ap.add_argument("--backend", default=None,
@@ -167,7 +170,7 @@ def main(argv=None):
         spec = page_spec(arch)
         retriever = Retriever(cfg)
         state, queries, targets, pages, _ = build_pages(
-            retriever, k_build, spec, args.pages, args.chunk, args.queries)
+            retriever, k_build, spec, args.pages, args.queries)
         relevant = [np.array([t]) for t in targets]
         print(f"indexed {pages} pages")
     else:

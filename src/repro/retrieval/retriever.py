@@ -127,9 +127,11 @@ class Retriever:
         rerank = bool(cfg.rerank) and not backend.exact_scores
 
         def merge(scores, ids, kk):
-            for ax in axes:
-                scores = jax.lax.all_gather(scores, ax, axis=1, tiled=True)
-                ids = jax.lax.all_gather(ids, ax, axis=1, tiled=True)
+            # one gather over all corpus axes lays the devices' rows out in
+            # doc order, so equal scores still resolve to the lower id (a
+            # gather per axis would put the last axis outermost)
+            scores = jax.lax.all_gather(scores, axes, axis=1, tiled=True)
+            ids = jax.lax.all_gather(ids, axes, axis=1, tiled=True)
             # every device contributes kk rows, so kk <= the gathered width
             top, pos = jax.lax.top_k(scores, kk)  # noqa: JAX04
             return top, jnp.take_along_axis(ids, pos, axis=1)

@@ -53,8 +53,6 @@ def topo():
     try:
         yield topologies.get_topology_desc(platform="tpu",
                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - any failure to describe
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
 
