@@ -19,7 +19,6 @@ Metrics (paper Table V definitions):
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Sequence
 
 import jax
@@ -30,6 +29,7 @@ from repro.core import pipeline as hpc
 from repro.models import transformer as T
 from repro.retrieval.base import Query as RQuery
 from repro.retrieval.retriever import Retriever
+from repro.tracing import Tracer
 
 Array = jax.Array
 
@@ -124,23 +124,24 @@ def rag_pipeline(index: "hpc.HPCIndex", gen_params, corpus, rag_cfg: RAGConfig,
     q_tok = corpus.query_tokens[queries_slice]
     gold_facts = np.asarray(corpus.gold_facts[queries_slice])
 
-    t0 = time.perf_counter()
+    # each stage's span ends once its device work is done
+    tracer = Tracer()
     retriever = Retriever(rag_cfg.retriever)
-    _, ids = retriever.search(index, RQuery(q_emb, q_mask, q_sal),
-                              k=rag_cfg.top_k_docs)
-    ids = jnp.maximum(ids, 0)
-    t_retrieve = time.perf_counter() - t0
+    with tracer.span("rag.retrieve"):
+        _, ids = retriever.search(index, RQuery(q_emb, q_mask, q_sal),
+                                  k=rag_cfg.top_k_docs)
+        ids = jax.block_until_ready(jnp.maximum(ids, 0))
 
     doc_toks = corpus.doc_tokens[ids]                     # (B, k, Ld)
     keep = rag_cfg.facts_per_doc + 1
     prompt_len = rag_cfg.top_k_docs * keep + q_tok.shape[1]
     prompt = build_prompt(doc_toks, q_tok, rag_cfg, prompt_len)
 
-    t1 = time.perf_counter()
-    gen = greedy_generate(gen_params, prompt, lm_cfg, rag_cfg.max_answer,
-                          prompt_len)
-    gen = np.asarray(jax.block_until_ready(gen))
-    t_generate = time.perf_counter() - t1
+    with tracer.span("rag.generate"):
+        gen = greedy_generate(gen_params, prompt, lm_cfg, rag_cfg.max_answer,
+                              prompt_len)
+        gen = np.asarray(jax.block_until_ready(gen))
+    t_retrieve, t_generate = (s.ms / 1e3 for s in tracer.records())
 
     ctx_facts_arr = np.asarray(corpus.doc_facts)[np.asarray(ids)]  # (B,k,F)
     ctx_sets = [set(row.ravel().tolist()) for row in ctx_facts_arr]

@@ -161,29 +161,36 @@ def _streaming_topk(score_block, payload: tuple, doc_ids: Array,
         jnp.issubdtype(jnp.dtype(score_dtype), jnp.floating) else sent
 
     def merge(carry, start, t):
-        """Score docs [start, start+t) and fold into the (B, k) buffer."""
+        """Score docs [start, start+t) and fold into the (B, k) buffer.
+
+        Device stages, as `jax.named_scope`s: `scan.kernel` scores the
+        block, `scan.merge` folds the scores into the buffer. (The code
+        kernels' cast and transpose of the block nest inside
+        `scan.kernel` as their own `kernel.layout`.)"""
         top_s, top_i = carry
         blk = tuple(jax.lax.dynamic_slice_in_dim(a, start, t, axis)
                     for a in payload)
         ids = jax.lax.dynamic_slice_in_dim(doc_ids, start, t,
                                            doc_ids.ndim - 1)
         v = jax.lax.dynamic_slice_in_dim(valid, start, t, valid.ndim - 1)
-        s = score_block(*blk)                                 # (B, T)
-        if v.ndim == 1:
-            v = jnp.broadcast_to(v[None], s.shape)
-        if ids.ndim == 1:
-            ids = jnp.broadcast_to(ids[None], s.shape)
-        # Caller-invalid slots (empty buckets, unreachable beam rows)
-        # score exactly NEG_INF — the v0 convention. (Unfilled buffer
-        # rows keep the init sentinel, strictly below every real doc.)
-        s = jnp.where(v, s, invalid_score)
-        ids = jnp.where(v, ids, -1)
-        # Carried buffer first: equal scores resolve to the earlier
-        # (lower-id) document, matching one global lax.top_k.
-        cat_s = jnp.concatenate([top_s, s], axis=1)
-        cat_i = jnp.concatenate([top_i, ids], axis=1)
-        new_s, sel = jax.lax.top_k(cat_s, k)
-        return new_s, jnp.take_along_axis(cat_i, sel, axis=1)
+        with jax.named_scope("scan.kernel"):
+            s = score_block(*blk)                             # (B, T)
+        with jax.named_scope("scan.merge"):
+            if v.ndim == 1:
+                v = jnp.broadcast_to(v[None], s.shape)
+            if ids.ndim == 1:
+                ids = jnp.broadcast_to(ids[None], s.shape)
+            # Caller-invalid slots (empty buckets, unreachable beam rows)
+            # score exactly NEG_INF — the v0 convention. (Unfilled buffer
+            # rows keep the init sentinel, strictly below every real doc.)
+            s = jnp.where(v, s, invalid_score)
+            ids = jnp.where(v, ids, -1)
+            # Carried buffer first: equal scores resolve to the earlier
+            # (lower-id) document, matching one global lax.top_k.
+            cat_s = jnp.concatenate([top_s, s], axis=1)
+            cat_i = jnp.concatenate([top_i, ids], axis=1)
+            new_s, sel = jax.lax.top_k(cat_s, k)
+            return new_s, jnp.take_along_axis(cat_i, sel, axis=1)
 
     # Full blocks sweep under lax.scan; a ragged N % block tail is scored
     # once at its natural (static) size — no padded corpus copy, no
@@ -248,7 +255,8 @@ def quantized_maxsim_topk(q: Array, q_mask: Array, codes: Array,
     per_query = codes.ndim == 3
     b = q.shape[0]
     n = codes.shape[1] if per_query else codes.shape[0]
-    table = li.adc_table(q, codebook)                     # (B, Mq, K)
+    with jax.named_scope("search.table"):
+        table = li.adc_table(q, codebook)                 # (B, Mq, K)
     doc_ids, valid = _prep(n, doc_ids, valid, per_query, b)
 
     if mode == "jnp":
@@ -278,14 +286,16 @@ def quantized_maxsim_topk(q: Array, q_mask: Array, codes: Array,
                     return qmaxsim_k.quantized_maxsim_pallas(
                         tab[None], qm1[None], cc.astype(jnp.int32),
                         mm.astype(jnp.float32), block_docs=tile,
-                        interpret=interpret)[0]
+                        interpret=interpret,
+                        name="quantized_maxsim_pallas_pool")[0]
                 return jax.vmap(one)(table, qm_f, c, m)
         else:
             def score_block(c, m):
                 tile = _kernel_tile(c.shape[0], 256, qfits, lane=True)
                 return qmaxsim_k.quantized_maxsim_pallas(
                     table, qm_f, c.astype(jnp.int32), m.astype(jnp.float32),
-                    block_docs=tile, interpret=interpret)
+                    block_docs=tile, interpret=interpret,
+                    name="quantized_maxsim_pallas_scan")
 
     return _streaming_topk(score_block, (codes, d_mask), doc_ids, valid,
                            b=b, n=n, k=k, block_docs=scan.block_docs,
@@ -415,7 +425,8 @@ def hamming_maxsim_topk(q_codes: Array, q_mask: Array, d_codes: Array,
                     return hamming_k.hamming_maxsim_pallas(
                         q1[None], qm1[None], d1.astype(jnp.int32),
                         m1.astype(jnp.float32), bits=bits,
-                        block_docs=tile, interpret=interpret)[0]
+                        block_docs=tile, interpret=interpret,
+                        name="hamming_maxsim_pallas_pool")[0]
                 out = jax.vmap(one)(q_codes, qm_f, d, m)
                 return jnp.maximum(out, float(ii.min)).astype(jnp.int32)
         else:
@@ -423,7 +434,8 @@ def hamming_maxsim_topk(q_codes: Array, q_mask: Array, d_codes: Array,
                 tile = _kernel_tile(d.shape[0], 256, hfits, lane=True)
                 out = hamming_k.hamming_maxsim_pallas(
                     q_codes, qm_f, d.astype(jnp.int32), m.astype(jnp.float32),
-                    bits=bits, block_docs=tile, interpret=interpret)
+                    bits=bits, block_docs=tile, interpret=interpret,
+                    name="hamming_maxsim_pallas_scan")
                 # only the lower bound can be exceeded (NEG_INF-masked
                 # sums); -2^31 is f32-exact, real scores are far below 2^31
                 return jnp.maximum(out, float(ii.min)).astype(jnp.int32)

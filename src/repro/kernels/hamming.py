@@ -63,15 +63,17 @@ def _hamming_kernel(bits_ref, q_ref, qm_ref, d_ref, dm_ref, out_ref):
     out_ref[0] = jnp.sum(per_q * qm_ref[0], axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "block_docs", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bits", "block_docs",
+                                             "interpret", "name"))
 def hamming_maxsim_pallas(q_codes, q_mask, d_codes, d_mask, *, bits: int,
-                          block_docs: int = 256, interpret: bool = False):
+                          block_docs: int = 256, interpret: bool = False,
+                          name: str = "hamming_maxsim_pallas"):
     """q_codes (B, Mq) int, d_codes (N, Md) int, masks f32 ->
     scores (B, N) f32.  N % block_docs == 0; on the chip block_docs is
-    a multiple of 128 or N."""
+    a multiple of 128 or N. `name` is the kernel's name in errors and
+    in a profiler trace."""
     b, mq = q_codes.shape
     n, md = d_codes.shape
-    name = "hamming_maxsim_pallas"
     vmem.check_divisible(n, block_docs, kernel=name)
     if not interpret:
         vmem.check_lane_tile(n, block_docs, kernel=name)
@@ -83,7 +85,9 @@ def hamming_maxsim_pallas(q_codes, q_mask, d_codes, d_mask, *, bits: int,
     rows = ((0, 0), (0, vmem.pad_rows(mq) - mq))
     qc = jnp.pad(q_codes.astype(jnp.int32) & mask_b, rows)[:, :, None]
     qm = jnp.pad(q_mask.astype(jnp.float32), rows)[:, :, None]
-    dc = (d_codes.astype(jnp.int32) & mask_b).T
+    with jax.named_scope("kernel.layout"):      # docs on lanes
+        dc = (d_codes.astype(jnp.int32) & mask_b).T
+        dm = d_mask.astype(jnp.float32).T
     bits_arr = jnp.full((1, 1), bits, jnp.int32)
     mq_p = qc.shape[1]
     out = pl.pallas_call(
@@ -105,5 +109,6 @@ def hamming_maxsim_pallas(q_codes, q_mask, d_codes, d_mask, *, bits: int,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b, 1, n), jnp.float32),
         interpret=interpret,
-    )(bits_arr, qc, qm, dc, d_mask.astype(jnp.float32).T)
+        name=name,
+    )(bits_arr, qc, qm, dc, dm)
     return out[:, 0, :]

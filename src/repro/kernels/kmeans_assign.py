@@ -81,5 +81,6 @@ def kmeans_assign_pallas(x, centroids, *, block_n: int = 256,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
         interpret=interpret,
+        name=name,
     )(x.astype(jnp.float32), centroids.astype(jnp.float32))
     return out[0]

@@ -98,6 +98,7 @@ def maxsim_pallas(q, q_mask, docs, d_mask, *, block_docs: int = 16,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((nb, b, 1, block_docs), jnp.float32),
         interpret=interpret,
+        name="maxsim_pallas",
     )(q.astype(jnp.float32), q_mask.astype(jnp.float32)[:, :, None],
       docs.astype(jnp.float32), d_mask.astype(jnp.float32)[:, None, :])
     return jnp.moveaxis(out[:, :, 0, :], 1, 0).reshape(b, n)

@@ -101,15 +101,17 @@ def _qmaxsim_kernel(tab_ref, qm_ref, codes_ref, dm_ref, out_ref):
     out_ref[0] = jnp.sum(per_q * qm_ref[0], axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("block_docs", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_docs", "interpret", "name"))
 def quantized_maxsim_pallas(table, q_mask, codes, d_mask, *,
-                            block_docs: int = 256, interpret: bool = False):
+                            block_docs: int = 256, interpret: bool = False,
+                            name: str = "quantized_maxsim_pallas"):
     """table (B, Mq, K) f32, q_mask (B, Mq) f32, codes (N, Md) int,
     d_mask (N, Md) f32 -> scores (B, N) f32.  N % block_docs == 0; on
-    the chip block_docs is a multiple of 128 or N."""
+    the chip block_docs is a multiple of 128 or N. `name` is the
+    kernel's name in errors and in a profiler trace."""
     b, mq, k = table.shape
     n, md = codes.shape
-    name = "quantized_maxsim_pallas"
     vmem.check_divisible(n, block_docs, kernel=name)
     if not interpret:
         vmem.check_lane_tile(n, block_docs, kernel=name)
@@ -123,6 +125,9 @@ def quantized_maxsim_pallas(table, q_mask, codes, d_mask, *,
         split_bf16(jnp.pad(table.astype(jnp.float32), rows + ((0, 0),))),
         axis=1)                                           # (B, 3*Mq_p, K)
     qm = jnp.pad(q_mask.astype(jnp.float32), rows)[:, :, None]
+    with jax.named_scope("kernel.layout"):      # docs on lanes
+        codes_t = codes.astype(jnp.int32).T
+        mask_t = d_mask.astype(jnp.float32).T
     out = pl.pallas_call(
         _qmaxsim_kernel,
         grid=(n // block_docs, b),
@@ -140,5 +145,6 @@ def quantized_maxsim_pallas(table, q_mask, codes, d_mask, *,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((b, 1, n), jnp.float32),
         interpret=interpret,
-    )(tab3, qm, codes.astype(jnp.int32).T, d_mask.astype(jnp.float32).T)
+        name=name,
+    )(tab3, qm, codes_t, mask_t)
     return out[:, 0, :]

@@ -76,21 +76,27 @@ class Retriever:
                ) -> Tuple[Array, Array]:
         """Online query (paper §III-E2 steps 2-5).
 
-        Returns (scores (B, k), doc_ids (B, k)).
+        Returns (scores (B, k), doc_ids (B, k)). The stages are
+        `jax.named_scope`s on the device: `search.prune`, `search.scan`
+        (with `search.table` and the scan engine's `scan.*` inside) and
+        `search.rerank`.
         """
         cfg, backend = self.cfg, self.backend
-        pruned = self._prune_query(query)
+        with jax.named_scope("search.prune"):
+            pruned = self._prune_query(query)
 
         # Steps 3-4 — backend candidate search (over-fetch for rerank).
         # All backends take the full v1 signature with `scan=` — legacy
         # out-of-tree backends get a kwargs-stripping shim at registration
         # (base.register_backend), so no signature sniffing here.
-        scores, ids = backend.search(state, pruned, k=self._n_cand(k),
-                                     scan=cfg.scan)
+        with jax.named_scope("search.scan"):
+            scores, ids = backend.search(state, pruned, k=self._n_cand(k),
+                                         scan=cfg.scan)
 
         # Step 5 — rerank candidates with unpruned quantized MaxSim.
         if cfg.rerank and not backend.exact_scores:
-            return self._rerank(state, pruned, scores, ids, k=k)
+            with jax.named_scope("search.rerank"):
+                return self._rerank(state, pruned, scores, ids, k=k)
         return scores[:, :k], ids[:, :k]
 
     def search_sharded(self, state: RetrieverState, query: Query, *, k: int,
@@ -137,19 +143,22 @@ class Retriever:
             return top, jnp.take_along_axis(ids, pos, axis=1)
 
         def local(st, q):
-            pruned = self._prune_query(q)
-            scores, ids = merge(*backend.search(st, pruned, k=n_cand,
-                                                scan=cfg.scan), n_cand)
+            with jax.named_scope("search.prune"):
+                pruned = self._prune_query(q)
+            with jax.named_scope("search.scan"):
+                scores, ids = merge(*backend.search(st, pruned, k=n_cand,
+                                                    scan=cfg.scan), n_cand)
             if not rerank:
                 return scores[:, :k], ids[:, :k]
-            rows = st.rerank_codes.shape[0]
-            row = ids - jax.lax.axis_index(axes) * rows
-            mine = (ids >= 0) & (row >= 0) & (row < rows)
-            row = jnp.clip(row, 0, rows - 1)
-            return merge(*scan_mod.quantized_maxsim_topk(
-                pruned.embeddings, pruned.mask, st.rerank_codes[row],
-                st.rerank_mask[row], st.codebook, k=k, doc_ids=ids,
-                valid=mine, scan=cfg.scan), k)
+            with jax.named_scope("search.rerank"):
+                rows = st.rerank_codes.shape[0]
+                row = ids - jax.lax.axis_index(axes) * rows
+                mine = (ids >= 0) & (row >= 0) & (row < rows)
+                row = jnp.clip(row, 0, rows - 1)
+                return merge(*scan_mod.quantized_maxsim_topk(
+                    pruned.embeddings, pruned.mask, st.rerank_codes[row],
+                    st.rerank_mask[row], st.codebook, k=k, doc_ids=ids,
+                    valid=mine, scan=cfg.scan), k)
 
         return jax.shard_map(local, mesh=mesh, in_specs=(specs, P()),
                              out_specs=(P(), P()),

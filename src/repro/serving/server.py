@@ -37,9 +37,16 @@ error instead of hanging until their client-side timeout. A facade
 ``query`` that times out *cancels* its queued item (and counts it in
 ``stats()["timeouts"]``) so abandoned requests stop occupying batch slots.
 
-Latency percentiles (p50/p99) are tracked per request, matching the paper's
-Table IV metric definitions; ``stats()`` additionally reports per-ladder-rung
-batch occupancy so under-filled compiled shapes are visible.
+Every request and batch is recorded as spans on a `repro.tracing.Tracer`
+(``tracer=``; by default one of its own, with no profiler annotations):
+``serve.request`` and ``serve.queue`` per request, ``serve.batch`` and its
+stages ``serve.coalesce`` / ``serve.slot`` / ``serve.stage`` /
+``serve.compute`` / ``serve.d2h`` / ``serve.fanout`` per batch, plus the
+counters ``serve.timeouts``, ``serve.deadline_expired`` and
+``serve.watchdog_restarts``. ``stats()`` reads its latency percentiles
+(p50/p99, per request, the paper's Table IV definitions), mean batch, qps
+and per-ladder-rung batch occupancy from those records, and its resilience
+counts from those counters.
 """
 from __future__ import annotations
 
@@ -56,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import Tracer
 from repro.serving.resilience import (AdmissionController,
                                       DeadlineExceeded,
                                       DegradationController,
@@ -133,14 +141,18 @@ class ServeConfig:
 class _Item:
     """One queued query inside the asyncio server."""
 
-    __slots__ = ("q_emb", "q_mask", "q_sal", "future", "t_enqueue",
-                 "deadline", "slo")
+    __slots__ = ("q_emb", "q_mask", "q_sal", "future", "t_enqueue_ns",
+                 "t_claim_ns", "span_id", "deadline", "slo")
 
-    def __init__(self, q_emb, q_mask, q_sal, future, t_enqueue,
+    def __init__(self, q_emb, q_mask, q_sal, future, t_enqueue_ns, span_id,
                  deadline=None, slo="interactive"):
         self.q_emb, self.q_mask, self.q_sal = q_emb, q_mask, q_sal
         self.future = future
-        self.t_enqueue = t_enqueue
+        # time.perf_counter_ns() at enqueue
+        self.t_enqueue_ns = t_enqueue_ns
+        self.t_claim_ns = None    # when the dispatcher took it (ns)
+        # the request's id: its `serve.request` span, recorded at fan-out
+        self.span_id = span_id
         # absolute time.perf_counter() deadline, or None
         self.deadline = deadline
         self.slo = slo
@@ -160,12 +172,18 @@ class AsyncRetrievalServer:
     degradation ladder serves from ``degraded_fns[L - 1]``. They must be
     pre-compiled shapes of the same ladder (see `LiveIndexSession` /
     `cascade.degrade_rungs`) so stepping down never compiles.
+
+    ``tracer`` receives the server's spans and counters (module
+    docstring); None gives the server a `Tracer` of its own with
+    ``annotate=False``.
     """
 
     def __init__(self, search_fn: Callable, cfg: ServeConfig,
-                 degraded_fns: Sequence[Callable] = ()):
+                 degraded_fns: Sequence[Callable] = (),
+                 tracer: Optional[Tracer] = None):
         self.search_fns: List[Callable] = [search_fn, *degraded_fns]
         self.cfg = cfg
+        self.tracer = tracer if tracer is not None else Tracer()
         self.ladder = cfg.resolved_ladder()
         self.recompile_sentry = None
         if cfg.guard_recompiles:
@@ -217,21 +235,14 @@ class AsyncRetrievalServer:
         self._claimed: Dict[_Item, float] = {}
         self._beat = 0.0  # dispatcher heartbeat (loop.time())
         # -- stats (threading lock: read from facade threads, written from
-        # fan-out tasks; the wall-clock span invariant is the same as v1:
-        # qps = requests / (first enqueue -> last completion), never the sum
-        # of overlapping per-request latencies) --
+        # fan-out tasks). stats() reads the tracer's records that end after
+        # `_since_ns` (the last reset_stats), and its counters --
         self._lock = threading.Lock()
-        self.latencies_ms: List[float] = []
-        self.batch_sizes: List[int] = []
-        self._rung_counts: Dict[int, int] = {}
-        self._rung_occupied: Dict[int, int] = {}
+        self._since_ns = 0
         self._level_served: Dict[int, int] = {}
+        # control, not tracing: the degradation controller's recent
+        # latencies, fed from the same fan-out stamp as `serve.request`
         self._recent_lat: collections.deque = collections.deque(maxlen=256)
-        self._n_timeouts = 0
-        self._n_deadline_expired = 0
-        self._n_watchdog_restarts = 0
-        self._t_first_enqueue: Optional[float] = None
-        self._t_last_done: Optional[float] = None
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -294,20 +305,19 @@ class AsyncRetrievalServer:
             if reason is not None:
                 raise Overloaded(reason)
         res = self.cfg.resilience
-        t_enq = time.perf_counter() if _t_enqueue is None else _t_enqueue
+        t_ns = (time.perf_counter_ns() if _t_enqueue is None
+                else int(_t_enqueue * 1e9))
         if deadline_ms is None and res is not None \
                 and res.default_deadline_ms > 0:
             deadline_ms = res.default_deadline_ms
-        deadline = None if deadline_ms is None else t_enq + deadline_ms / 1e3
+        deadline = (None if deadline_ms is None
+                    else t_ns / 1e9 + deadline_ms / 1e3)
         fut = asyncio.get_running_loop().create_future()
         item = _Item(
             # client inputs are host arrays by contract — no device sync
             np.asarray(q_emb), np.asarray(q_mask), np.asarray(q_sal), fut,  # noqa: JAX05
-            t_enq, deadline, slo,
+            t_ns, self.tracer.new_id(), deadline, slo,
         )
-        with self._lock:
-            if self._t_first_enqueue is None:
-                self._t_first_enqueue = t_enq
         await self._queue.put(item)
         return item
 
@@ -423,8 +433,7 @@ class AsyncRetrievalServer:
             return True
         if item.deadline is not None \
                 and time.perf_counter() >= item.deadline:
-            with self._lock:
-                self._n_deadline_expired += 1
+            self.tracer.count("serve.deadline_expired")
             self._resolve_exc(item, DeadlineExceeded(
                 "deadline passed while queued — dropped before staging"))
             return True
@@ -443,13 +452,17 @@ class AsyncRetrievalServer:
 
     async def _dispatch(self) -> None:
         loop = asyncio.get_running_loop()
+        tracer = self.tracer
         while True:
             self._beat = loop.time()
             item = await self._queue.get()
             self._beat = loop.time()
             if item is _STOP:
                 return
-            self._claimed[item] = time.perf_counter()
+            # taken off the queue: its `serve.queue` span ends here (fan-out
+            # records it, off the coalescing path)
+            t_first = item.t_claim_ns = time.perf_counter_ns()
+            self._claimed[item] = t_first / 1e9
             self.fault_injector.fire("dispatch")
             if self._closing:
                 self._resolve_exc(item, ServerClosed(
@@ -471,7 +484,8 @@ class AsyncRetrievalServer:
                 if nxt is _STOP:
                     stop_after = True
                     break
-                self._claimed[nxt] = time.perf_counter()
+                nxt.t_claim_ns = time.perf_counter_ns()
+                self._claimed[nxt] = nxt.t_claim_ns / 1e9
                 if not self._drop_stale(nxt):
                     batch.append(nxt)
             # deadlines/cancellations may have landed while coalescing
@@ -480,10 +494,16 @@ class AsyncRetrievalServer:
                 if stop_after:
                     return
                 continue
+            batch_id = tracer.new_id()
+            t_coalesced = time.perf_counter_ns()
+            tracer.mark("serve.coalesce", t_first, t_coalesced,
+                        parent=batch_id, batch=batch_id)
             level = self._observe_level()
             # bound in-flight batches (double buffer): once a slot frees we
             # stage the next batch here while the previous one still computes
             await self._inflight.acquire()
+            tracer.mark("serve.slot", t_coalesced, time.perf_counter_ns(),
+                        parent=batch_id, batch=batch_id)
             # the wait for a slot can be long under load: re-check for
             # cancellations/deadlines that landed during it
             batch = [r for r in batch if not self._drop_stale(r)]
@@ -493,7 +513,10 @@ class AsyncRetrievalServer:
                     return
                 continue
             try:
-                staged = self._stage(batch, level)
+                with tracer.span("serve.stage", parent=batch_id,
+                                 batch=batch_id, rung=self.rung_for(
+                                     len(batch))):
+                    staged = self._stage(batch, level)
             except Exception as e:  # noqa: BLE001 - e.g. mixed-shape batch
                 # fail this batch but keep the dispatcher alive: a staging
                 # error (say, two coalesced queries with different Mq) must
@@ -508,7 +531,8 @@ class AsyncRetrievalServer:
                 # handed to fan-out, which owns resolution from here; the
                 # watchdog only covers the dequeue->stage window
                 self._claimed.pop(r, None)
-            task = loop.create_task(self._fanout(batch, level, *staged))
+            task = loop.create_task(self._fanout(
+                batch, level, *staged, batch_id=batch_id, t_first=t_first))
             self._fanout_tasks.add(task)
             task.add_done_callback(self._fanout_tasks.discard)
             if stop_after:
@@ -549,8 +573,7 @@ class AsyncRetrievalServer:
 
     def _restart_dispatcher(self, loop, exc: DispatcherFailed) -> None:
         self._fail_claimed(exc)
-        with self._lock:
-            self._n_watchdog_restarts += 1
+        self.tracer.count("serve.watchdog_restarts")
         self._beat = loop.time()
         self._dispatcher = loop.create_task(self._dispatch())
 
@@ -570,57 +593,62 @@ class AsyncRetrievalServer:
         return rung, jnp.asarray(q), jnp.asarray(qm), jnp.asarray(qs)
 
     async def _fanout(self, batch: List[_Item], level: int, rung: int,
-                      q, qm, qs) -> None:
+                      q, qm, qs, *, batch_id: int, t_first: int) -> None:
         loop = asyncio.get_running_loop()
+        tracer = self.tracer
 
         def _compute():
             self.fault_injector.fire("compute")
-            out = self._call_search(level, q, qm, qs)
-            jax.block_until_ready(out)  # only blocking point, off the loop
+            with tracer.span("serve.compute", parent=batch_id,
+                             batch=batch_id, rung=rung):
+                out = self._call_search(level, q, qm, qs)
+                jax.block_until_ready(out)  # only blocking point, off loop
             # device->host transfer stays on the executor thread too: done
             # on the event loop it head-of-line blocked every coalesced
             # request behind one D2H copy (JAX05)
-            return np.asarray(out[0]), np.asarray(out[1])
+            with tracer.span("serve.d2h", parent=batch_id, batch=batch_id):
+                return np.asarray(out[0]), np.asarray(out[1])
 
         try:
-            scores, ids = await loop.run_in_executor(self._pool, _compute)
+            work = loop.run_in_executor(self._pool, _compute)
+            # each request's wait in the queue, recorded while the batch
+            # computes: off the coalescing and staging path
+            for r in batch:
+                tracer.mark("serve.queue", r.t_enqueue_ns, r.t_claim_ns,
+                            parent=r.span_id, request=r.span_id)
+            scores, ids = await work
             self.fault_injector.fire("fanout")
         except Exception as e:  # noqa: BLE001 - forwarded to every waiter
             for r in batch:
                 self._resolve_exc(r, e)
             self._inflight.release()
             return
-        now = time.perf_counter()
-        with self._lock:
-            self._t_last_done = now
-            self.batch_sizes.append(len(batch))
-            self._rung_counts[rung] = self._rung_counts.get(rung, 0) + 1
-            self._rung_occupied[rung] = (
-                self._rung_occupied.get(rung, 0) + len(batch)
-            )
-            if self._t_first_enqueue is None:
-                # reset_stats() ran while this batch was in flight: restart
-                # the window at this batch's earliest enqueue so the
-                # span/latency invariant holds
-                self._t_first_enqueue = min(r.t_enqueue for r in batch)
+        with tracer.span("serve.fanout", parent=batch_id, batch=batch_id):
+            now_ns = time.perf_counter_ns()
+            now = now_ns / 1e9
             for r in batch:
-                lat_ms = (now - r.t_enqueue) * 1e3
-                self.latencies_ms.append(lat_ms)
-                self._recent_lat.append(lat_ms)
-        for i, r in enumerate(batch):
-            if r.deadline is not None and now >= r.deadline:
-                # result arrived, but nobody is waiting for it anymore
-                with self._lock:
-                    self._n_deadline_expired += 1
-                self._resolve_exc(r, DeadlineExceeded(
-                    "deadline passed during compute"))
-                continue
-            if not r.future.done():
-                r.future.set_result(Served((scores[i], ids[i]), level))
-                with self._lock:
-                    self._level_served[level] = (
-                        self._level_served.get(level, 0) + 1
-                    )
+                tracer.mark("serve.request", r.t_enqueue_ns, now_ns,
+                            span_id=r.span_id, request=r.span_id,
+                            batch=batch_id)
+            with self._lock:
+                self._recent_lat.extend(
+                    (now_ns - r.t_enqueue_ns) / 1e6 for r in batch)
+            for i, r in enumerate(batch):
+                if r.deadline is not None and now >= r.deadline:
+                    # result arrived, but nobody is waiting for it anymore
+                    tracer.count("serve.deadline_expired")
+                    self._resolve_exc(r, DeadlineExceeded(
+                        "deadline passed during compute"))
+                    continue
+                if not r.future.done():
+                    r.future.set_result(Served((scores[i], ids[i]), level))
+                    with self._lock:
+                        self._level_served[level] = (
+                            self._level_served.get(level, 0) + 1
+                        )
+        tracer.mark("serve.batch", t_first, time.perf_counter_ns(),
+                    span_id=batch_id, batch=batch_id, rung=rung,
+                    requests=tuple(r.span_id for r in batch))
         self._inflight.release()
 
     # -- stats --------------------------------------------------------------
@@ -629,56 +657,76 @@ class AsyncRetrievalServer:
         """Caller holds self._lock. The timeout counter is unconditional
         (sync-facade timeouts cancel their queued item on any server); the
         overload/degradation counters only exist on a guarded server."""
-        out: Dict[str, Any] = {"timeouts": self._n_timeouts}
+        counters = self.tracer.counters
+        out: Dict[str, Any] = {"timeouts": counters.get("serve.timeouts", 0)}
         if self.cfg.resilience is None:
             return out
         shed = (self._admission.stats() if self._admission is not None
                 else {"interactive": 0, "batch": 0})
         out.update({
-            "deadline_expired": self._n_deadline_expired,
+            "deadline_expired": counters.get("serve.deadline_expired", 0),
             "shed": sum(shed.values()),
             "shed_interactive": shed["interactive"],
             "shed_batch": shed["batch"],
             "degrade_level": (self._degrade.level
                               if self._degrade is not None else 0),
             "level_served": dict(self._level_served),
-            "watchdog_restarts": self._n_watchdog_restarts,
+            "watchdog_restarts": counters.get("serve.watchdog_restarts", 0),
         })
         return out
 
-    def stats(self) -> Dict[str, Any]:
+    def _window(self):
+        """The `serve.request` and `serve.batch` records of the stats
+        window: those that ended after the last `reset_stats`."""
         with self._lock:
-            lat = np.array(self.latencies_ms)
-            batch_sizes = list(self.batch_sizes)
-            rungs = {
-                b: {
-                    "batches": self._rung_counts[b],
-                    "occupancy": self._rung_occupied[b]
-                    / (self._rung_counts[b] * b),
-                }
-                for b in sorted(self._rung_counts)
-            }
-            t0, t1 = self._t_first_enqueue, self._t_last_done
+            since = self._since_ns
+        recs = self.tracer.records(since_ns=since)
+        return ([s for s in recs if s.name == "serve.request"],
+                [s for s in recs if s.name == "serve.batch"])
+
+    @property
+    def latencies_ms(self) -> List[float]:
+        """Enqueue-to-answer latency of each request answered in the stats
+        window, in the order they were answered."""
+        return [s.ms for s in self._window()[0]]
+
+    @property
+    def batch_sizes(self) -> List[int]:
+        """Real requests of each batch run in the stats window."""
+        return [len(s.ids["requests"]) for s in self._window()[1]]
+
+    def stats(self) -> Dict[str, Any]:
+        """Latency, batch and rung figures of the requests and batches
+        whose records end in the window (the ring keeps the last
+        `repro.tracing.CAPACITY` records); resilience counts since the
+        last reset_stats."""
+        reqs, batches = self._window()
+        per_rung: Dict[int, List[int]] = {}
+        for s in batches:
+            c = per_rung.setdefault(s.ids["rung"], [0, 0])
+            c[0] += 1
+            c[1] += len(s.ids["requests"])
+        rungs = {b: {"batches": n_b, "occupancy": rows / (n_b * b)}
+                 for b, (n_b, rows) in sorted(per_rung.items())}
+        with self._lock:
             res = self._resilience_stats()
-        if lat.size == 0:
+        if not reqs:
             # no traffic yet: report zeros, never fabricated percentiles
             return {"n": 0, "p50_ms": 0.0, "p99_ms": 0.0, "mean_batch": 0.0,
                     "qps": 0.0, "rungs": {}, **res}
-        # span comes from monotonic first/last timestamps ONLY; the fan-out
-        # backfill keeps (lat nonempty => t0/t1 set) true even when
-        # reset_stats races a completing batch, so a missing timestamp
-        # means no completed window — report qps 0, never a value derived
-        # from summed overlapping latencies
-        if t0 is None or t1 is None:
-            qps = 0.0
-        else:
-            qps = lat.size / max(t1 - t0, 1e-9)
+        lat = np.array([s.ms for s in reqs])
+        # qps over the wall-clock window, first enqueue -> last answer,
+        # never from summed overlapping per-request latencies
+        t0 = min(s.start_ns for s in reqs)
+        t1 = max(s.end_ns for s in reqs)
         return {
             "n": int(lat.size),
             "p50_ms": float(np.percentile(lat, 50)),
             "p99_ms": float(np.percentile(lat, 99)),
-            "mean_batch": float(np.mean(batch_sizes)) if batch_sizes else 0.0,
-            "qps": qps,
+            "mean_batch": (float(np.mean([len(s.ids["requests"])
+                                          for s in batches]))
+                           if batches else 0.0),
+            "qps": lat.size / max((t1 - t0) / 1e9, 1e-9),
             "rungs": rungs,
             **res,
         }
@@ -691,20 +739,15 @@ class AsyncRetrievalServer:
         return self.recompile_sentry.report()
 
     def reset_stats(self) -> None:
-        """Drop recorded latencies and the serving window (e.g. after a
-        warmup/compile request, which would otherwise skew qps). Resilience
+        """Start a new stats window (e.g. after a warmup/compile request,
+        which would otherwise skew qps): stats() reads only records that
+        end from now on. The tracer's ring keeps its records. Resilience
         counters reset too, except watchdog_restarts (lifetime health)."""
         with self._lock:
-            self.latencies_ms = []
-            self.batch_sizes = []
-            self._rung_counts = {}
-            self._rung_occupied = {}
+            self._since_ns = time.perf_counter_ns()
             self._level_served = {}
             self._recent_lat.clear()
-            self._n_timeouts = 0
-            self._n_deadline_expired = 0
-            self._t_first_enqueue = None
-            self._t_last_done = None
+        self.tracer.drop_counters("serve.timeouts", "serve.deadline_expired")
         if self._admission is not None:
             self._admission.reset()
 
@@ -797,8 +840,7 @@ class RetrievalServer:
             req.abandoned = True
             if req.item is not None and not req.item.future.done():
                 req.item.future.cancel()
-            with self._async._lock:
-                self._async._n_timeouts += 1
+            self._async.tracer.count("serve.timeouts")
 
         try:
             self._loop.call_soon_threadsafe(_cancel)
@@ -830,6 +872,10 @@ class RetrievalServer:
     @property
     def ladder(self) -> Tuple[int, ...]:
         return self._async.ladder
+
+    @property
+    def tracer(self) -> Tracer:
+        return self._async.tracer
 
     @property
     def latencies_ms(self) -> List[float]:

@@ -192,10 +192,12 @@ def test_close_raises_when_thread_fails_to_join():
 
 
 def test_qps_span_from_timestamps_only():
-    """qps must come from the monotonic first/last window. If the window
-    is missing (reset_stats raced the last completion), report 0.0 —
-    never the old sum-of-overlapping-latencies fallback, which inflated
-    qps by orders of magnitude under concurrency."""
+    """qps must come from the monotonic first-enqueue -> last-answer
+    window of the requests' own `serve.request` records — never the old
+    sum-of-overlapping-latencies fallback, which inflated qps by orders
+    of magnitude under concurrency. The window is read from the records
+    themselves, so there is no separate timestamp that a reset_stats
+    race could leave missing."""
     server = RetrievalServer(_fake_search,
                              ServeConfig(max_batch=4, max_wait_ms=1.0))
     try:
@@ -205,14 +207,13 @@ def test_qps_span_from_timestamps_only():
         assert st["n"] == 4 and st["qps"] > 0.0
         span = st["n"] / st["qps"]
         assert span <= 60.0                   # sane wall-clock window
-        # simulate the race: latencies present, window cleared
-        srv = server._async
-        with srv._lock:
-            srv._t_first_enqueue = None
-            srv._t_last_done = None
-        st = server.stats()
-        assert st["n"] == 4
-        assert st["qps"] == 0.0               # degraded fallback is gone
+        reqs = server.tracer.records("serve.request")
+        assert len(reqs) == 4
+        window_s = (max(s.end_ns for s in reqs)
+                    - min(s.start_ns for s in reqs)) / 1e9
+        assert st["qps"] == pytest.approx(4 / window_s)
+        assert st["p50_ms"] == pytest.approx(
+            float(np.percentile([s.ms for s in reqs], 50)))
     finally:
         server.close()
 
