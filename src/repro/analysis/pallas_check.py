@@ -366,7 +366,8 @@ def _qmaxsim_site(name: str, *, b: int, mq: int, k: int, md: int,
                   block: int, notes: str = "") -> KernelSite:
     """One ADC-kernel geometry; ``block`` is the scan block (the pallas
     call scores one scan block per invocation), the inner doc tile is
-    picked exactly as core/scan.py picks it (VMEM-aware)."""
+    picked exactly as core/scan.py picks it (VMEM-aware, for one query),
+    and the kernel then groups as many of the ``b`` queries as fit."""
     def build():
         from repro.core.scan import _kernel_tile
         from repro.kernels import quantized_maxsim as qk
@@ -443,6 +444,10 @@ _SITES: Tuple[KernelSite, ...] = (
                   notes="the docstring's K<=512 envelope — the formerly "
                         "unchecked bound; the VMEM-aware tile picker "
                         "must shrink the doc tile to fit"),
+    _qmaxsim_site("qmaxsim_colpali_b64", b=64, mq=32, k=256, md=615,
+                  block=256,
+                  notes="the colpali-hpc ladder's top rung: two groups of "
+                        "32 queries, the widest group that fits"),
     _maxsim_site("maxsim_manifest", b=8, mq=8, md=16, d=16, block=256),
     _maxsim_site("maxsim_serving", b=8, mq=32, md=64, d=128, block=256,
                  notes="the docstring's worked VMEM example"),

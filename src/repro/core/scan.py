@@ -276,8 +276,10 @@ def quantized_maxsim_topk(q: Array, q_mask: Array, codes: Array,
         md_n = codes.shape[-1]
 
         def qfits(tile):
+            # the tile is sized for one query a step; the kernel then
+            # scores as many queries a step as fit (query_group)
             return vmem.fits(qmaxsim_k.qmaxsim_vmem_bytes(
-                tile, mq_n, k_n, md_n))
+                tile, mq_n, k_n, md_n, g=1))
 
         if per_query:
             def score_block(c, m):

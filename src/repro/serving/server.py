@@ -42,11 +42,13 @@ Every request and batch is recorded as spans on a `repro.tracing.Tracer`
 ``serve.request`` and ``serve.queue`` per request, ``serve.batch`` and its
 stages ``serve.coalesce`` / ``serve.slot`` / ``serve.stage`` /
 ``serve.compute`` / ``serve.d2h`` / ``serve.fanout`` per batch, plus the
-counters ``serve.timeouts``, ``serve.deadline_expired`` and
-``serve.watchdog_restarts``. ``stats()`` reads its latency percentiles
-(p50/p99, per request, the paper's Table IV definitions), mean batch, qps
-and per-ladder-rung batch occupancy from those records, and its resilience
-counts from those counters.
+counters ``serve.timeouts``, ``serve.deadline_expired``,
+``serve.watchdog_restarts`` and ``serve.onehot_shared_queries`` (the real
+queries of batches whose rung the ADC kernel scores in query groups,
+`kernels.quantized_maxsim.traced_group`). ``stats()`` reads its latency
+percentiles (p50/p99, per request, the paper's Table IV definitions),
+mean batch, qps and per-ladder-rung batch occupancy from those records,
+and its resilience counts from those counters.
 """
 from __future__ import annotations
 
@@ -63,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import quantized_maxsim as qmaxsim_k
 from repro.tracing import Tracer
 from repro.serving.resilience import (AdmissionController,
                                       DeadlineExceeded,
@@ -527,6 +530,10 @@ class AsyncRetrievalServer:
                 if stop_after:
                     return
                 continue
+            if qmaxsim_k.traced_group(staged[0]) > 1:
+                # this rung's ADC scan scores its queries in groups, one
+                # one-hot per (block, patch) a group
+                tracer.count("serve.onehot_shared_queries", len(batch))
             for r in batch:
                 # handed to fan-out, which owns resolution from here; the
                 # watchdog only covers the dequeue->stage window
@@ -742,12 +749,14 @@ class AsyncRetrievalServer:
         """Start a new stats window (e.g. after a warmup/compile request,
         which would otherwise skew qps): stats() reads only records that
         end from now on. The tracer's ring keeps its records. Resilience
-        counters reset too, except watchdog_restarts (lifetime health)."""
+        counters reset too, except watchdog_restarts (lifetime health),
+        and so does serve.onehot_shared_queries."""
         with self._lock:
             self._since_ns = time.perf_counter_ns()
             self._level_served = {}
             self._recent_lat.clear()
-        self.tracer.drop_counters("serve.timeouts", "serve.deadline_expired")
+        self.tracer.drop_counters("serve.timeouts", "serve.deadline_expired",
+                                  "serve.onehot_shared_queries")
         if self._admission is not None:
             self._admission.reset()
 

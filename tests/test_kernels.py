@@ -94,6 +94,47 @@ def test_adc_table_split_is_exact():
     np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
 
 
+@pytest.mark.parametrize("b,k,mq,group", [
+    (1, 16, 8, 1), (3, 16, 8, 3), (8, 16, 8, 8),
+    (1, 256, 32, 1), (3, 256, 32, 3), (8, 256, 32, 8),
+    (3, 16, 1200, 2),     # groups of 2 over 3 queries: one padded row
+    (8, 16, 850, 3),      # groups of 3 over 8 queries: one padded row
+])
+def test_adc_kernel_batch_equals_per_query_calls(b, k, mq, group):
+    """Queries scored in groups against one one-hot per (block, patch)
+    give each query exactly what a call of its own gives, padded groups
+    included; masked query tokens, masked patches and a page with every
+    patch masked."""
+    from repro.kernels import quantized_maxsim as qk
+    md, n, tile = 8, 256, 128
+    assert qk.query_group(b, mq, k, md, tile) == group
+    ks = jax.random.split(jax.random.PRNGKey(b * mq + k), 4)
+    table = jax.random.normal(ks[0], (b, mq, k)) * 10.0
+    qm = (jax.random.uniform(ks[1], (b, mq)) > 0.25).astype(jnp.float32)
+    codes = jax.random.randint(ks[2], (n, md), 0, k)
+    dm = (jax.random.uniform(ks[3], (n, md)) > 0.3).at[5].set(False)
+    dm = dm.astype(jnp.float32)
+    got = qk.quantized_maxsim_pallas(table, qm, codes, dm, block_docs=tile,
+                                     interpret=True)
+    alone = [qk.quantized_maxsim_pallas(table[i:i + 1], qm[i:i + 1], codes,
+                                        dm, block_docs=tile, interpret=True)
+             for i in range(b)]
+    assert np.array_equal(np.asarray(got), np.concatenate(alone))
+
+
+def test_query_group_is_one_at_b1_and_fits_at_the_top_rung():
+    from repro.kernels import quantized_maxsim as qk
+    from repro.kernels import vmem
+    for mq, k, md, tile in [(32, 256, 615, 256), (8, 16, 8, 128),
+                            (32, 512, 128, 2048)]:
+        assert qk.query_group(1, mq, k, md, tile) == 1
+    g = qk.query_group(64, 32, 256, 615, 256)
+    assert g == 32
+    assert vmem.fits(qk.qmaxsim_vmem_bytes(256, 32, 256, 615, g))
+    # one group of 64 does not fit, so the batch takes two of 32
+    assert not vmem.fits(qk.qmaxsim_vmem_bytes(256, 32, 256, 615, 64))
+
+
 def test_kernel_consistency_with_core_library(rng):
     """ops.quantized_maxsim (kernel path) == core.late_interaction ADC."""
     from repro.core import late_interaction as li

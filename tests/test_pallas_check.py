@@ -35,7 +35,7 @@ def test_registered_kernel_sites_are_clean():
     sites = kernel_sites()
     assert {s.name for s in sites} >= {
         "qmaxsim_manifest", "qmaxsim_serving", "qmaxsim_k512",
-        "maxsim_serving", "hamming_serving", "kmeans_assign_default"}
+        "qmaxsim_colpali_b64", "maxsim_serving", "hamming_serving", "kmeans_assign_default"}
     assert check_all() == []
 
 

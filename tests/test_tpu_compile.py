@@ -77,12 +77,16 @@ def _qfits(mq, k, md):
     return lambda t: vmem.fits(qk.qmaxsim_vmem_bytes(t, mq, k, md))
 
 
-def test_adc_kernel_compiles_at_colpali_widths(one_chip):
+@pytest.mark.parametrize("b", [1, B])
+def test_adc_kernel_compiles_at_colpali_widths(one_chip, b):
+    """The ladder's bottom and top rungs: one query a group at B=1, the
+    widest group that fits at the top."""
     tile = scan_mod._kernel_tile(BLOCK, 256, _qfits(MQ, K, MD), lane=True)
+    assert qk.query_group(b, MQ, K, MD, tile) == (1 if b == 1 else 32)
     _compile(lambda t, qm, c, m: qk.quantized_maxsim_pallas(
         t, qm, c, m, block_docs=tile),
-        _sds((B, MQ, K), jnp.float32, one_chip),
-        _sds((B, MQ), jnp.float32, one_chip),
+        _sds((b, MQ, K), jnp.float32, one_chip),
+        _sds((b, MQ), jnp.float32, one_chip),
         _sds((BLOCK, MD), jnp.int32, one_chip),
         _sds((BLOCK, MD), jnp.float32, one_chip))
 

@@ -228,3 +228,49 @@ def test_threads_share_a_tracer_without_losing_records():
     outer = {r.id for r in recs if r.name == "outer"}
     assert all(r.parent in outer for r in recs if r.name == "inner")
     assert tr.counters == {"n": n_threads * per, "m": 2 * n_threads * per}
+
+
+@pytest.mark.parametrize("backend", ["flat", "hamming"])
+def test_onehot_shared_queries_counts_queries_scored_in_groups(
+        monkeypatch, backend):
+    """`serve.onehot_shared_queries` counts the real queries of every
+    batch whose rung the traced ADC scan scores in query groups (rungs
+    above 1); a Hamming index runs no ADC kernel and counts none."""
+    import jax
+
+    from repro.data import synthetic
+    from repro.kernels import quantized_maxsim as qk
+    from repro.retrieval import Corpus, HPCConfig, Query, Retriever
+
+    monkeypatch.setattr(qk, "_TRACED_GROUPS", {})
+    key = jax.random.PRNGKey(0)
+    spec = synthetic.CorpusSpec(n_docs=64, n_queries=8, n_patches=8,
+                                n_q_patches=4, dim=16, n_topics=4)
+    data = synthetic.make_retrieval_corpus(key, spec)
+    r = Retriever(HPCConfig(k=16, backend=backend, scan_impl="interpret",
+                            kmeans_iters=3, kmeans_restarts=1))
+    state = r.build(key, Corpus(data.doc_patches, data.doc_mask,
+                                data.doc_salience))
+    search = jax.jit(lambda q, qm, qs: r.search(state, Query(q, qm, qs),
+                                                k=5))
+    queries = [tuple(np.asarray(a[i]) for a in (
+        data.query_patches, data.query_mask, data.query_salience))
+        for i in range(8)]
+
+    async def main():
+        srv = AsyncRetrievalServer(search, ServeConfig(max_batch=4,
+                                                       max_wait_ms=20.0))
+        srv.warm_shapes(*queries[0])
+        await srv.start()
+        await srv.query(*queries[0])                  # a batch of one
+        await asyncio.gather(*[srv.query(*q) for q in queries[1:]])
+        await srv.aclose()
+        return srv
+
+    srv = asyncio.run(main())
+    grouped = sum(len(b.ids["requests"])
+                  for b in srv.tracer.records("serve.batch")
+                  if b.ids["rung"] > 1)
+    counted = srv.tracer.counters.get("serve.onehot_shared_queries", 0)
+    assert grouped >= 2
+    assert counted == (grouped if backend == "flat" else 0)
