@@ -10,6 +10,10 @@ name, so a later change adds one by adding a file:
   traffic/<mix>.json         one traffic mix: its driver and parameters
   traffic/<driver>.py        a generator of traffic (`drive`)
   references/<name>.py       a plain reference (`scores`)
+  systems/<name>.py          how the index is built and searched on the
+                             cell's chips (`CHIPS`, `build`, `compile`),
+                             named by a configuration's "system", else
+                             `one_chip`
   kernels/<kernel>.py        a kernel's trace pattern and its counts
   metrics/<metric>.py        one metric's reader (`read`); a metric split
                              by its cells' end-to-end metric, such as
@@ -57,6 +61,11 @@ class Catalog:
                      if c["name"] == name)
         with open(os.path.join(self.checkout, entry["file"])) as f:
             return json.load(f)
+
+    def system(self, config: dict):
+        """The system module the configuration names (`"system"`), by
+        default `systems/one_chip.py`."""
+        return self.module("systems", config.get("system", "one_chip"))
 
     def cell(self, name: str) -> dict:
         """The cell's entry in BENCHMARK.json, merged with its files:

@@ -38,15 +38,16 @@ def search_ms_per_query(run):
 
 
 def roofline_pct(run, kernel: str):
-    """The kernel's least time on this chip over its device time, in %,
-    over every search in the trace. The least time of a search is the
-    larger of its operations over the chip's highest operation rate and
-    its bytes over the HBM bandwidth."""
-    if run.trace is None:
+    """The kernel's least time over its device time, in %, over every
+    search in the trace. The least time of a search is the larger of its
+    operations over one chip's highest operation rate and its bytes over
+    its HBM bandwidth; the counts are of the cell's whole work, and the
+    device time is summed over the cell's chips, so work split over
+    chips reads at most 100%."""
+    if not run.traces:
         return None
     mod = run.catalog.module("kernels", kernel)
-    lo, hi = run.trace_bounds
-    ns = run.trace.kernel_ns(mod.PATTERN, lo, hi)
+    ns = sum(t.kernel_ns(mod.PATTERN) for t in run.traces)
     if ns <= 0:
         return None
     peak_ops = max(run.peaks["ops_per_s"].values())
@@ -58,11 +59,18 @@ def roofline_pct(run, kernel: str):
 
 
 def busy_s(run):
-    """Seconds in the measured window in which an op ran on the chip."""
+    """Seconds in the measured window in which an op ran on the first
+    chip."""
     if run.trace is None:
         return None
     lo, hi = run.trace.window()
     return run.trace.busy(lo, hi)[0] / 1e9
+
+
+def busy_s_per_chip(run):
+    """`busy_s` of each of the cell's chips, in mesh order."""
+    lo, hi = run.trace.window()
+    return [t.busy(lo, hi)[0] / 1e9 for t in run.traces]
 
 
 def window_s(run):

@@ -47,9 +47,7 @@ def main(argv=None) -> int:
     from benchmarks.chip.catalog import Catalog
 
     catalog = Catalog()
-    entry = catalog.cell(args.workload)["entry"]
-    device = run_cell.require_devices(entry["chips"],
-                                      catalog.json(".", "peaks"))[0]
+    devices = run_cell.cell_devices(catalog, args.workload)
     run_cell.enable_compile_cache()
     controls = tuple(refcore.CODEBOOK_CONTROLS)
     for seed in (int(s) for s in args.seeds.split(",")):
@@ -59,8 +57,9 @@ def main(argv=None) -> int:
                                                    seed, controls)}),
                   flush=True)
             continue
-        cell = run_cell.Cell(catalog, args.workload, seed, annotate=False)
-        run = cell.window(cell.mix, args.seconds, seed, False, device)
+        cell = run_cell.Cell(catalog, args.workload, seed, annotate=False,
+                             devices=devices)
+        run = cell.window(cell.mix, args.seconds, seed, False)
         reference = catalog.module("references", cell.config["reference"])
         answers, refs, rows, unanswered, excess = cell.references(
             run, seed, reference.VARIANTS, controls)
@@ -86,8 +85,9 @@ def main(argv=None) -> int:
 
 def first_chunk_codebook(catalog, name: str, seed: int, controls):
     """The codebook numbers of the program's fit on the cell's first
-    chunk of pages (as `system.build_index` builds it), of the program's
-    fit with one restart, and of the control fits."""
+    chunk of pages (as `systems/one_chip.py` builds it; a system that
+    fits on a mesh is not read here), of the program's fit with one
+    restart, and of the control fits."""
     import time
 
     from benchmarks.chip import pages as pages_mod

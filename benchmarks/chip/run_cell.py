@@ -4,19 +4,24 @@
   python3 benchmarks/chip/run_cell.py --workload <cell> --seed <n> \
       --seconds <s> --trace <0|1>
 
-Set-up builds the cell's index from `--seed` (pages made on the device,
-`Retriever.build` / `add` / `compact`), compiles `Retriever.search` once
-per ladder rung, serves it with `AsyncRetrievalServer` and runs once
-each rung the cell's traffic uses. The window then offers the cell's traffic for `--seconds`.
-After it, the run reads the peak device memory, frees the index, and
+Set-up builds the cell's index from `--seed` on the cell's chips (pages
+made on the device; how it is built and searched is the configuration's
+system, `systems/<name>.py`: on one chip `Retriever.build` / `add` /
+`compact`), compiles the search once per ladder rung, serves it with
+`AsyncRetrievalServer` and runs once each rung the cell's traffic uses.
+The window then offers the cell's traffic for `--seconds`. After it,
+the run reads the peak device memory of every chip, frees the index, and
 compares a seeded sample of the served answers with the plain reference.
 
 The last line of standard output is one JSON object: `correct`,
 `attempted`, `failed`, `metrics` (the cell's end-to-end metrics, or with
-`--trace 1` its per-layer metrics), `device`, with `--trace 1`
-`breakdown`, and last `checks`, each number compared beside its limit.
-Without a TPU, with fewer chips than the cell asks for, or with a chip
-that `peaks.json` does not list, it exits non-zero and prints no result.
+`--trace 1` its per-layer metrics), `device` (every chip of the cell
+read: the fullest chip's peak memory, and with `--trace 1` the busy time
+averaged over the chips), with `--trace 1` `breakdown`, and last
+`checks`, each number compared beside its limit.
+Without a TPU, with fewer chips than the cell asks for or a count its
+system does not serve, or with a chip that `peaks.json` does not list,
+it exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -51,31 +56,44 @@ class RunRefused(RuntimeError):
 class Run:
     """What a finished window leaves for the metric readers."""
 
-    trace = None
-    trace_bounds = None
+    chips = 1
+    trace = None        # the first chip's
+    traces = ()         # one a chip, in mesh order
     traced_spans = ()
 
 
-def require_devices(chips: int, peaks: dict, devs=None):
+def require_devices(chips: int, peaks: dict, devs=None, allowed=(1,)):
     """The first `chips` of `devs` (JAX's devices), or RunRefused: no
-    TPU, too few chips, more than one chip, or a chip the peaks table
-    does not list."""
+    TPU, a count other than 1 or 4 or than the system serves
+    (`allowed`), too few chips, or a chip the peaks table does not
+    list."""
     import jax
 
     devs = jax.devices() if devs is None else devs
     if devs[0].platform != "tpu":
         raise RunRefused(f"JAX found no TPU (platform {devs[0].platform!r})")
+    if chips not in (1, 4):
+        raise RunRefused(f"the cell asks for {chips} chips; a cell takes "
+                         "1 or 4")
+    if chips not in allowed:
+        raise RunRefused(f"the cell asks for {chips} chips; its system "
+                         f"serves on {tuple(allowed)}")
     if len(devs) < chips:
         raise RunRefused(f"the cell asks for {chips} chips, JAX found "
                          f"{len(devs)}")
-    if chips != 1:
-        raise RunRefused(f"the cell asks for {chips} chips; the harness "
-                         "builds and serves on one chip (no sharded "
-                         "search path yet)")
-    if devs[0].device_kind not in peaks["devices"]:
-        raise RunRefused(f"no peaks for device kind "
-                         f"{devs[0].device_kind!r} in peaks.json")
+    for d in devs[:chips]:
+        if d.device_kind not in peaks["devices"]:
+            raise RunRefused(f"no peaks for device kind "
+                             f"{d.device_kind!r} in peaks.json")
     return devs[:chips]
+
+
+def cell_devices(catalog, name: str, devs=None):
+    """`require_devices` for cell `name`: the chips it asks for, as its
+    configuration's system serves them."""
+    cell = catalog.cell(name)
+    return require_devices(cell["entry"]["chips"], catalog.json(".", "peaks"),
+                           devs, allowed=catalog.system(cell["config"]).CHIPS)
 
 
 def enable_compile_cache() -> str:
@@ -121,15 +139,17 @@ def serve_window(server, pool, driver, mix, seconds, seed, give_up_s,
 
 
 class Cell:
-    """A cell's system under test, set up: the index built from the seed,
-    the search compiled per ladder rung, and the rungs the cell's traffic
-    uses (`warm_rungs` of its workload file) run once.
+    """A cell's system under test, set up on its chips (`devices`, as
+    `cell_devices` picks them): the index built
+    from the seed and the search compiled per ladder rung by the
+    configuration's system, and the rungs the cell's traffic uses
+    (`warm_rungs` of its workload file) run once.
 
     `wrap_search(fn) -> fn`, if given, wraps the served search function
     (the tests plant faults with it)."""
 
     def __init__(self, catalog, name: str, seed: int, *, annotate: bool,
-                 wrap_search=None):
+                 devices, wrap_search=None):
         from benchmarks.chip import system
         from repro.retrieval import HPCConfig, Retriever
         from repro.serving.server import ServeConfig
@@ -138,20 +158,21 @@ class Cell:
         cell = catalog.cell(name)
         self.cell, self.config = cell, cell["config"]
         self.workload, self.mix = cell["workload"], cell["mix"]
+        self.devices = list(devices)
         enc = self.config["encoder"]
         retriever = Retriever(HPCConfig(**self.config["hpc"]))
         self.serve_cfg = ServeConfig(max_batch=self.config["max_batch"],
                                      top_k=self.config["top_k"])
         self.phases = system.Phases()
-        self.state, self.pool = system.build_index(
-            retriever, self.config, seed, self.workload["pages"],
-            self.workload["chunk_pages"], self.workload["queries"],
+        system_mod = catalog.system(self.config)
+        self.state, self.pool = system_mod.build(
+            retriever, self.config, seed, self.workload, self.devices,
             self.phases)
         t0 = time.perf_counter()
-        compiled = system.compile_search(
+        compiled = system_mod.compile(
             retriever, self.state, top_k=self.config["top_k"],
             rungs=self.serve_cfg.resolved_ladder(), mq=enc["query_len"],
-            d=enc["proj_dim"])
+            d=enc["proj_dim"], devices=self.devices)
         self.phases.seconds["compile"] = time.perf_counter() - t0
         self.search = system.SearchSpans(compiled, self.state, annotate)
         self.served_fn = (wrap_search(self.search) if wrap_search
@@ -164,9 +185,10 @@ class Cell:
         self.search.spans.clear()
         self.index_bytes = system.resident_bytes(self.state)
 
-    def window(self, mix: dict, seconds: float, seed: int, trace: bool,
-               device) -> Run:
-        """Serve `mix` for one window through a new server."""
+    def window(self, mix: dict, seconds: float, seed: int,
+               trace: bool) -> Run:
+        """Serve `mix` for one window through a new server; with `trace`,
+        read every chip's trace."""
         import gc
         import shutil
         import tempfile
@@ -179,8 +201,9 @@ class Cell:
         out = Run()
         out.catalog, out.config = self.catalog, self.config
         out.pages, out.index_bytes = self.workload["pages"], self.index_bytes
+        out.chips = len(self.devices)
         out.peaks = self.catalog.json(".", "peaks")["devices"].get(
-            device.device_kind)
+            self.devices[0].device_kind)
         server = AsyncRetrievalServer(self.served_fn, self.serve_cfg)
         driver = self.catalog.module("traffic", mix["driver"])
         log_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -213,11 +236,11 @@ class Cell:
         out.spans = list(self.search.spans)
         if trace:
             jax.profiler.stop_trace()
-            out.trace = trace_mod.Trace.from_profile(
-                trace_mod.find_profile(log_dir), f"/device:TPU:{device.id}")
+            out.traces = trace_mod.Trace.from_profile(
+                trace_mod.find_profile(log_dir),
+                [f"/device:TPU:{d.id}" for d in self.devices])
+            out.trace = out.traces[0]
             shutil.rmtree(log_dir, ignore_errors=True)
-            out.trace_bounds = (min([o[1] for o in out.trace.ops] or [0]),
-                                max([o[2] for o in out.trace.ops] or [0]))
             out.traced_spans = out.spans
         return out
 
@@ -280,31 +303,41 @@ class Cell:
 
 
 def run(catalog, name: str, seed: int, seconds: float, trace: bool,
-        device, *, wrap_search=None):
-    """One run of cell `name` on `device`, the one chip it builds and
+        devices, *, wrap_search=None):
+    """One run of cell `name` on `devices`, the chips it builds and
     serves on; returns the result dict."""
     from benchmarks.chip import readers
+    from benchmarks.chip import trace as trace_mod
 
-    cell = Cell(catalog, name, seed, annotate=trace,
+    cell = Cell(catalog, name, seed, annotate=trace, devices=devices,
                 wrap_search=wrap_search)
-    out = cell.window(cell.mix, seconds, seed, trace, device)
-    memory_peak = (device.memory_stats() or {}).get("peak_bytes_in_use")
+    out = cell.window(cell.mix, seconds, seed, trace)
+    memory = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+              for d in cell.devices]
     checks, correct = cell.check(out, seed)
     metrics = {}
     for m in cell.cell["per_layer" if trace else "end_to_end"]:
         value = catalog.module("metrics", m["name"]).read(out)
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
-    dev = {"platform": device.platform, "kind": device.device_kind,
-           "count": 1, "memory_peak_bytes": memory_peak}
+    first = cell.devices[0]
+    dev = {"platform": first.platform, "kind": first.device_kind,
+           "count": len(cell.devices),
+           "memory_peak_bytes": max((m for m in memory if m is not None),
+                                    default=None),
+           "memory_peak_bytes_per_chip": memory}
     result = {"correct": correct, "attempted": len(out.records),
               "failed": checks["unanswered"]["value"], "metrics": metrics,
               "device": dev}
     if trace:
         lo, hi = out.trace.window()
-        dev["busy_s"], dev["window_s"] = readers.busy_s(out), (hi - lo) / 1e9
-        result["breakdown"] = {"device_ops": out.trace.top_ops(lo, hi),
-                               "idle_gaps": out.trace.idle_gaps(lo, hi)}
+        busy = readers.busy_s_per_chip(out)
+        dev["busy_s"] = sum(busy) / len(busy)
+        dev["busy_s_per_chip"] = busy
+        dev["window_s"] = (hi - lo) / 1e9
+        result["breakdown"] = {
+            "device_ops": trace_mod.top_ops(out.traces, lo, hi),
+            "idle_gaps": out.trace.idle_gaps(lo, hi)}
     result["setup_phases_s"] = cell.phases.seconds
     result["checks"] = checks        # last: the numbers beside their limits
     return result
@@ -326,12 +359,10 @@ def main(argv=None) -> int:
         from benchmarks.chip.catalog import Catalog
 
         catalog = Catalog()
-        entry = catalog.cell(args.workload)["entry"]
-        devices = require_devices(entry["chips"],
-                                  catalog.json(".", "peaks"))
+        devices = cell_devices(catalog, args.workload)
         enable_compile_cache()
         result = run(catalog, args.workload, args.seed, args.seconds,
-                     bool(args.trace), devices[0])
+                     bool(args.trace), devices)
     except (RunRefused, ImportError, OSError, KeyError) as e:
         print(f"run_cell: no result: {e!r}", file=sys.stderr, flush=True)
         return 2

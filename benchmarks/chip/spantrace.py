@@ -79,18 +79,19 @@ class ScopedTrace(Trace):
     # -- reading ------------------------------------------------------------
 
     @classmethod
-    def from_profile(cls, path: str, device: str, hlo: dict | None = None):
-        """Read the chip `device`'s op line and the host spans of the
-        `.xplane.pb` at `path`. An op's scope is looked up in `hlo`
-        (`hlo_scopes` of the programs that ran)."""
+    def from_profile(cls, path: str, devices, hlo: dict | None = None):
+        """One trace per chip named in `devices`, in that order, from one
+        read of the `.xplane.pb` at `path`: the chip's op line and every
+        host span. An op's scope is looked up in `hlo` (`hlo_scopes` of
+        the programs that ran)."""
         from jax.profiler import ProfileData
 
         hlo = hlo or {}
         prof = ProfileData.from_file(path)
-        ops, spans = [], []
+        ops, spans = {d: [] for d in devices}, []
         by_event = {}                 # event name -> (op name, scope)
         for plane in prof.planes:
-            if plane.name == device:
+            if plane.name in ops:
                 for line in plane.lines:
                     if line.name != OPS_LINE:
                         continue
@@ -102,8 +103,9 @@ class ScopedTrace(Trace):
                                 op_name(name),
                                 hlo.get(instruction(name), ""))
                         start = int(e.start_ns)
-                        ops.append((got[0], start,
-                                    start + int(e.duration_ns), got[1]))
+                        ops[plane.name].append((got[0], start,
+                                                start + int(e.duration_ns),
+                                                got[1]))
             elif plane.name.startswith("/host:"):
                 for line in plane.lines:
                     for e in line.events:
@@ -114,10 +116,13 @@ class ScopedTrace(Trace):
                                    if e.name.startswith("serve.") else {})
                             spans.append((e.name, start,
                                           start + int(e.duration_ns), ids))
-        ops.sort(key=lambda o: o[1])
         spans.sort(key=lambda s: s[1])
-        return cls([o[:3] for o in ops], [s[:3] for s in spans],
-                   [o[3] for o in ops], [s[3] for s in spans])
+        out = []
+        for d in devices:
+            chip = sorted(ops[d], key=lambda o: o[1])
+            out.append(cls([o[:3] for o in chip], [s[:3] for s in spans],
+                           [o[3] for o in chip], [s[3] for s in spans]))
+        return out
 
     def to_json(self, path: str) -> None:
         names = sorted(set(self.scopes))
@@ -144,7 +149,7 @@ class ScopedTrace(Trace):
 
     def self_ns(self, lo: int, hi: int) -> list:
         """Self time in [lo, hi] of each op, by its index in `ops`: its
-        time less that of the ops it encloses (as `Trace.top_ops`).
+        time less that of the ops it encloses (as `Trace.op_self_ns`).
         Kept for the next call with the same bounds."""
         if (lo, hi) not in self._memo:
             self._memo.clear()

@@ -67,11 +67,10 @@ def main(argv=None) -> int:
     from benchmarks.chip.catalog import Catalog
 
     catalog = Catalog()
-    entry = catalog.cell(args.workload)["entry"]
-    device = run_cell.require_devices(entry["chips"],
-                                      catalog.json(".", "peaks"))[0]
+    devices = run_cell.cell_devices(catalog, args.workload)
     run_cell.enable_compile_cache()
-    cell = run_cell.Cell(catalog, args.workload, args.seed, annotate=False)
+    cell = run_cell.Cell(catalog, args.workload, args.seed, annotate=False,
+                         devices=devices)
     print(json.dumps({"setup_phases_s": cell.phases.seconds}), flush=True)
     orders = [int(o) for o in args.orders.split(",") if o] or [
         cell.mix["order_seed"]]
@@ -81,7 +80,7 @@ def main(argv=None) -> int:
         for order in orders:
             run = cell.window(dict(cell.mix, rate_qps=rate,
                                    order_seed=order),
-                              args.seconds, args.seed, False, device)
+                              args.seconds, args.seed, False)
             line = dict(summary(run, rate), order_seed=order)
             ok = ok and sustained(line)
             print(json.dumps(line), flush=True)

@@ -1,9 +1,8 @@
-"""The system under test, driven through its public API only.
-
-`Retriever.build` on the first chunk of seeded pages (which fits the
-codebook), `Retriever.add` for every further chunk, one
-`Retriever.compact`; then `Retriever.search` compiled once per ladder
-rung with the index as an argument, served by `AsyncRetrievalServer`.
+"""What every system under test shares, driven through the program's
+public API only: the set-up's timed phases, the cell's seeded pages and
+queries, the served search function with its spans, and the index's
+device bytes. How the index is built and searched on the cell's chips is
+the configuration's system, `systems/<name>.py` (see `systems/one_chip.py`).
 """
 from __future__ import annotations
 
@@ -11,7 +10,6 @@ import threading
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.chip import pages as pages_mod
@@ -32,50 +30,32 @@ class Phases:
         return out
 
 
-def build_index(retriever, config: dict, seed: int, n_pages: int,
-                chunk: int, n_queries: int, phases: Phases):
-    """Index n_pages seeded pages; draw n_queries queries spread over
-    every chunk. Returns (state, queries (emb, mask, sal) as host
-    arrays)."""
-    from repro.retrieval import Corpus
-
+def seeded_chunks(config: dict, seed: int, workload: dict, phases: Phases):
+    """Yield the cell's seeded pages chunk by chunk (`pages.chunk_pages`;
+    a page's id is its place in this order), each made on the device in
+    phase "generate", with the queries drawn from that chunk: (pages,
+    queries). The queries are spread over every chunk; `query_pool`
+    joins them."""
     spec = pages_mod.spec_from(config)
-    k_bank, k_build, _, _ = pages_mod.corpus_keys(seed)
+    k_bank = pages_mod.corpus_keys(seed)[0]
     banks = pages_mod.make_topic_banks(k_bank, spec)
-    sizes = pages_mod.chunk_sizes(n_pages, chunk)
-    per_chunk = -(-n_queries // len(sizes))
-    state, qparts, offset = None, [], 0
+    sizes = pages_mod.chunk_sizes(workload["pages"], workload["chunk_pages"])
+    per_chunk = -(-workload["queries"] // len(sizes))
+    offset = 0
     for c, size in enumerate(sizes):
         pg = phases.run("generate", pages_mod.chunk_pages, seed, spec,
                         banks, c, size)
-        qparts.append(pages_mod.chunk_queries(seed, spec, pg, c, offset,
-                                              min(per_chunk, size)))
-        if state is None:
-            state = phases.run("build", retriever.build, k_build,
-                               Corpus(*pg))
-        else:
-            state = phases.run("add", retriever.add, state, Corpus(*pg))
+        yield pg, pages_mod.chunk_queries(seed, spec, pg, c, offset,
+                                          min(per_chunk, size))
         offset += size
         del pg
-    if len(sizes) > 1:
-        state = phases.run("compact", retriever.compact, state)
-    queries = tuple(np.concatenate([np.asarray(p[i]) for p in qparts])
-                    [:n_queries] for i in range(3))
-    return state, queries
 
 
-def compile_search(retriever, state, *, top_k: int, rungs, mq: int, d: int):
-    """`retriever.search` compiled once per rung, the state an argument.
-    Returns {rung: compiled}."""
-    from repro.retrieval import Query
-
-    fn = jax.jit(lambda st, q, qm, qs: retriever.search(
-        st, Query(q, qm, qs), k=top_k))
-    sds = jax.ShapeDtypeStruct
-    return {b: fn.lower(state, sds((b, mq, d), jnp.float32),
-                        sds((b, mq), jnp.bool_),
-                        sds((b, mq), jnp.float32)).compile()
-            for b in rungs}
+def query_pool(qparts: list, n_queries: int):
+    """The first n_queries of the queries `seeded_chunks` drew, chunk by
+    chunk in `qparts`, as host arrays (embeddings, mask, salience)."""
+    return tuple(np.concatenate([np.asarray(p[i]) for p in qparts])
+                 [:n_queries] for i in range(3))
 
 
 class SearchSpans:
@@ -110,5 +90,8 @@ class SearchSpans:
 
 
 def resident_bytes(state) -> int:
-    """Device bytes of the index state: the sum of its leaves' nbytes."""
-    return sum(int(x.nbytes) for x in jax.tree.leaves(state))
+    """Device bytes of the index state on the cell's chips: the bytes of
+    every shard of every leaf, so a leaf replicated on four chips counts
+    four times and a leaf split over them once."""
+    return sum(int(s.data.nbytes) for x in jax.tree.leaves(state)
+               for s in x.addressable_shards)

@@ -1,12 +1,13 @@
 """Reduction of a JAX profiler trace to device busy time, idle gaps and
 kernel time.
 
-`Trace.from_profile` reads the `.xplane.pb` the profiler wrote: the
-events of the chip's op line, one per HLO op (a `while` op encloses the
-ops of its body), each named by its HLO instruction, and the
-benchmark's own host spans (`bench.*` `TraceAnnotation`s). Everything
-after that works on those plain lists, so the reduction is checked on a
-recorded trace (`Trace.to_json` / `Trace.from_json`) without a chip.
+`Trace.from_profile` reads the `.xplane.pb` the profiler wrote into one
+`Trace` for each chip of a cell: the events of the chip's op line, one
+per HLO op (a `while` op encloses the ops of its body), each named by
+its HLO instruction, and the benchmark's own host spans (`bench.*`
+`TraceAnnotation`s). Everything after that works on those plain lists,
+so the reduction is checked on a recorded trace (`Trace.to_json` /
+`Trace.from_json`) without a chip.
 """
 from __future__ import annotations
 
@@ -38,30 +39,33 @@ class Trace:
     # -- reading ------------------------------------------------------------
 
     @classmethod
-    def from_profile(cls, path: str, device: str):
+    def from_profile(cls, path: str, devices):
+        """One trace per chip named in `devices` (`/device:TPU:<id>`), in
+        that order, from one read of the profile; each holds every
+        `bench.*` host span."""
         from jax.profiler import ProfileData
 
         prof = ProfileData.from_file(path)
-        out = cls()
+        ops, spans = {d: [] for d in devices}, []
         for plane in prof.planes:
-            if plane.name == device:
+            if plane.name in ops:
                 for line in plane.lines:
                     if line.name != OPS_LINE:
                         continue
                     for e in line.events:
                         start = int(e.start_ns)
-                        out.ops.append((op_name(e.name), start,
-                                        start + int(e.duration_ns)))
+                        ops[plane.name].append((op_name(e.name), start,
+                                                start + int(e.duration_ns)))
             elif plane.name.startswith("/host:"):
                 for line in plane.lines:
                     for e in line.events:
                         if e.name.startswith("bench."):
-                            out.spans.append((e.name, int(e.start_ns),
-                                              int(e.start_ns
-                                                  + e.duration_ns)))
-        out.ops.sort(key=lambda o: o[1])
-        out.spans.sort(key=lambda s: s[1])
-        return out
+                            spans.append((e.name, int(e.start_ns),
+                                          int(e.start_ns
+                                              + e.duration_ns)))
+        spans.sort(key=lambda s: s[1])
+        return [cls(sorted(ops[d], key=lambda o: o[1]), list(spans))
+                for d in devices]
 
     def to_json(self, path: str) -> None:
         with gzip.open(path, "wt") as f:
@@ -97,17 +101,18 @@ class Trace:
                 merged.append([s, e])
         return sum(e - s for s, e in merged), merged
 
-    def kernel_ns(self, pattern: str, lo: int, hi: int) -> int:
+    def kernel_ns(self, pattern: str, lo: int | None = None,
+                  hi: int | None = None) -> int:
         """Device time of the ops whose own name holds a match of
-        `pattern` (a regex), clipped to [lo, hi]."""
+        `pattern` (a regex), clipped to [lo, hi] where given."""
         rx = re.compile(pattern)
-        return sum(max(0, min(e, hi) - max(s, lo))
+        return sum(e - s if lo is None else max(0, min(e, hi) - max(s, lo))
                    for name, s, e in self.ops if rx.search(name))
 
-    def top_ops(self, lo: int, hi: int, n: int = 10):
-        """[[op name, seconds]] of the n ops (numbered instances summed)
-        with the most self time in [lo, hi]: an op's time less that of
-        the ops it encloses, so a `while` counts only its own gaps."""
+    def op_self_ns(self, lo: int, hi: int) -> dict:
+        """{op name: self time in ns} in [lo, hi], numbered instances
+        summed: an op's time less that of the ops it encloses, so a
+        `while` counts only its own gaps."""
         self_ns, stack = {}, []
         for name, s, e in sorted(self.ops, key=lambda o: (o[1], -o[2])):
             s, e = max(s, lo), min(e, hi)
@@ -120,8 +125,7 @@ class Trace:
             if stack and e <= stack[-1][1]:     # enclosed, not overlapping
                 self_ns[stack[-1][0]] -= e - s
             stack.append((key, e))
-        top = sorted(self_ns.items(), key=lambda kv: -kv[1])[:n]
-        return [[k, v / 1e9] for k, v in top]
+        return self_ns
 
     def idle_gaps(self, lo: int, hi: int, n: int = 10):
         """[[host span, seconds]] of the n longest gaps in [lo, hi] in
@@ -141,6 +145,18 @@ class Trace:
                     if open_ else "outside the window's spans")
             out.append([name, (e - s) / 1e9])
         return out
+
+
+def top_ops(traces, lo: int, hi: int, n: int = 10):
+    """[[op name, seconds]] of the n ops with the most self time in
+    [lo, hi] (`Trace.op_self_ns`), summed over `traces` (one a chip)
+    under the same name."""
+    total = {}
+    for t in traces:
+        for name, ns in t.op_self_ns(lo, hi).items():
+            total[name] = total.get(name, 0) + ns
+    top = sorted(total.items(), key=lambda kv: -kv[1])[:n]
+    return [[k, v / 1e9] for k, v in top]
 
 
 def find_profile(log_dir: str) -> str:

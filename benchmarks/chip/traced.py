@@ -5,20 +5,23 @@ beside the device trace.
   python3 benchmarks/chip/traced.py --workload <cell> --seed <n> \
       --seconds <s> [--untraced] [--fixture PATH]
 
-The cell is set up as `run_cell.py` sets it up. Its window then runs
-through a server given a `repro.tracing.Tracer(annotate=True)`, under
-the profiler. The trace is read as a `spantrace.ScopedTrace`: the
-device's ops with their program scopes (from the compiled programs' HLO
-where the trace does not carry them), and the server's `serve.*` spans
-with their ids. The tracer's records are mapped onto the trace's clock
-by the offset `served.clock_offset` measures. Every metric of the cell,
-and every metric whose reader is in `SPAN_METRICS`, is read.
+The cell is set up as `run_cell.py` sets it up, on its chips. Its window
+then runs through a server given a `repro.tracing.Tracer(annotate=True)`,
+under the profiler. Every chip's trace is read as a
+`spantrace.ScopedTrace`: the chip's ops with their program scopes (from
+the compiled programs' HLO where the trace does not carry them), and the
+server's `serve.*` spans with their ids. The tracer's records are mapped
+onto the trace's clock by the offset `served.clock_offset` measures.
+Every metric of the cell, and every metric whose reader is in
+`SPAN_METRICS`, is read; as in `run_cell.py`, a kernel's time is summed
+over the chips. The scoped breakdown, the offset and the span metrics
+read the first chip's trace alone.
 
 With `--untraced`, a window with no annotations and no profiler runs
 first, on the same set-up and seed: the two windows' metrics differ by
 what tracing costs. With `--fixture PATH`, no window runs: one B=1
-search is traced, with 5 ms of idle on either side, and written to PATH
-(`ScopedTrace.to_json`).
+search is traced, with 5 ms of idle on either side, and the first chip's
+trace is written to PATH (`ScopedTrace.to_json`).
 
 Prints one JSON line per window: `traced`, `metrics`, `offset_ns` and
 the residuals of the matched spans, and `breakdown`: device time by
@@ -61,7 +64,7 @@ def program_scopes(compiled: dict) -> dict:
     return out
 
 
-def traced_window(cell, seconds: float, seed: int, traced: bool, device,
+def traced_window(cell, seconds: float, seed: int, traced: bool,
                   hlo: dict):
     """One window of the cell's mix through a server with a tracer (with
     annotations and the profiler if `traced`); returns the `Run`."""
@@ -76,8 +79,9 @@ def traced_window(cell, seconds: float, seed: int, traced: bool, device,
     out = run_cell.Run()
     out.catalog, out.config = cell.catalog, cell.config
     out.pages, out.index_bytes = cell.workload["pages"], cell.index_bytes
+    out.chips = len(cell.devices)
     out.peaks = cell.catalog.json(".", "peaks")["devices"].get(
-        device.device_kind)
+        cell.devices[0].device_kind)
     tracer = Tracer(annotate=traced)
     server = AsyncRetrievalServer(cell.served_fn, cell.serve_cfg,
                                   tracer=tracer)
@@ -112,11 +116,11 @@ def traced_window(cell, seconds: float, seed: int, traced: bool, device,
     out.serve_counters = dict(tracer.counters)
     if traced:
         jax.profiler.stop_trace()
-        out.trace = ScopedTrace.from_profile(
-            find_profile(log_dir), f"/device:TPU:{device.id}", hlo)
+        out.traces = ScopedTrace.from_profile(
+            find_profile(log_dir),
+            [f"/device:TPU:{d.id}" for d in cell.devices], hlo)
+        out.trace = out.traces[0]
         shutil.rmtree(log_dir, ignore_errors=True)
-        out.trace_bounds = (min([o[1] for o in out.trace.ops] or [0]),
-                            max([o[2] for o in out.trace.ops] or [0]))
         out.traced_spans = out.spans
         got = served.clock_offset(out.serve_records, out.trace)
         if got is not None:
@@ -125,8 +129,11 @@ def traced_window(cell, seconds: float, seed: int, traced: bool, device,
 
 
 def breakdown(run) -> dict:
-    """What the per-layer metrics summarise, for PERF.md."""
-    from benchmarks.chip import served
+    """What the per-layer metrics summarise, for PERF.md: device time of
+    the first chip by scope, the top ops summed over every chip, and with
+    more chips each chip's busy time."""
+    from benchmarks.chip import readers, served
+    from benchmarks.chip import trace as trace_mod
 
     recs = run.serve_records
     n_batches = sum(1 for r in recs if r.name == "serve.batch") or 1
@@ -160,9 +167,11 @@ def breakdown(run) -> dict:
         "scope_ms": {k or "(none)": v / 1e6 for k, v in sorted(
             scopes.items(), key=lambda kv: -kv[1])},
         "attributed": run.trace.attributed_share(lo, hi, kernels),
-        "top_ops": run.trace.top_ops(lo, hi),
+        "top_ops": trace_mod.top_ops(run.traces, lo, hi),
         "idle_gaps": run.trace.idle_gaps(lo, hi),
         "idle_s_by_span": served.idle_by_span(run)})
+    if run.chips > 1:
+        out["busy_s_per_chip"] = readers.busy_s_per_chip(run)
     return out
 
 
@@ -178,9 +187,9 @@ def read_metrics(catalog, cell_entry, run) -> dict:
     return out
 
 
-def record_fixture(cell, path: str, device, hlo: dict) -> dict:
+def record_fixture(cell, path: str, hlo: dict) -> dict:
     """Trace one B=1 search through a traced server, 5 ms of idle on
-    either side, and write it to `path`."""
+    either side, and write the first chip's trace to `path`."""
     import jax
 
     from benchmarks.chip.spantrace import ScopedTrace
@@ -205,8 +214,8 @@ def record_fixture(cell, path: str, device, hlo: dict) -> dict:
         await server.aclose()
 
     asyncio.run(main())
-    trace = ScopedTrace.from_profile(find_profile(log_dir),
-                                     f"/device:TPU:{device.id}", hlo)
+    trace = ScopedTrace.from_profile(
+        find_profile(log_dir), [f"/device:TPU:{cell.devices[0].id}"], hlo)[0]
     shutil.rmtree(log_dir, ignore_errors=True)
     trace.to_json(path)
     lo, hi = trace.window()
@@ -234,18 +243,17 @@ def main(argv=None) -> int:
 
     catalog = Catalog()
     cell_entry = catalog.cell(args.workload)
-    device = run_cell.require_devices(cell_entry["entry"]["chips"],
-                                      catalog.json(".", "peaks"))[0]
+    devices = run_cell.cell_devices(catalog, args.workload)
     run_cell.enable_compile_cache()
-    cell = run_cell.Cell(catalog, args.workload, args.seed, annotate=True)
+    cell = run_cell.Cell(catalog, args.workload, args.seed, annotate=True,
+                         devices=devices)
     hlo = program_scopes(cell.search.compiled)
     if args.fixture:
-        print(json.dumps(record_fixture(cell, args.fixture, device, hlo)),
+        print(json.dumps(record_fixture(cell, args.fixture, hlo)),
               flush=True)
         return 0
     for traced in ([False] if args.untraced else []) + [True]:
-        run = traced_window(cell, args.seconds, args.seed, traced, device,
-                            hlo)
+        run = traced_window(cell, args.seconds, args.seed, traced, hlo)
         res = getattr(run, "residuals_ns", None)
         line = {"workload": args.workload, "seed": args.seed,
                 "traced": traced,

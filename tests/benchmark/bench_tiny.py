@@ -23,14 +23,23 @@ TINY = {
 }
 
 
-def tiny_catalog(tmp, backend: str = "flat") -> Catalog:
+def tiny_catalog(tmp, backend: str = "flat", system: str | None = None,
+                 chips: int = 1) -> Catalog:
     """Catalog of cells `tiny.open` (open loop) and `tiny.closed`
     (closed loop) over 512 tiny pages (48 clusters of patches for 64
     codes, as the real configurations have 240 for 256 or 512), in a
-    copy under `tmp`."""
+    copy under `tmp`. With `system`, the configuration names that
+    test-only system, `<system>_system.py` beside this file, copied in
+    as `systems/<system>.py`; the cells ask for `chips` chips."""
     root = os.path.join(str(tmp), "benchmarks", "chip")
     shutil.copytree(HERE, root, ignore=shutil.ignore_patterns(
         "__pycache__"))
+    extra = {}
+    if system is not None:
+        shutil.copy(os.path.join(os.path.dirname(__file__),
+                                 f"{system}_system.py"),
+                    os.path.join(root, "systems", f"{system}.py"))
+        extra["system"] = system
 
     def put(kind, name, obj):
         with open(os.path.join(root, kind, f"{name}.json"), "w") as f:
@@ -39,7 +48,7 @@ def tiny_catalog(tmp, backend: str = "flat") -> Catalog:
     put("configs", "tiny", dict(
         TINY[backend], name="tiny",
         encoder={"n_patches": 32, "query_len": 8, "proj_dim": 32},
-        top_k=16, max_batch=8, pages=TINY_PAGES))
+        top_k=16, max_batch=8, pages=TINY_PAGES, **extra))
     for cell in ("tiny.open", "tiny.closed"):
         put("workloads", cell, {"pages": 512, "chunk_pages": 256,
                                 "queries": 64, "check_requests": 16,
@@ -53,9 +62,9 @@ def tiny_catalog(tmp, backend: str = "flat") -> Catalog:
                          "reduced": [], "why": "tiny"}]
     bench["workloads"] = [
         {"name": "tiny.open", "config": "tiny", "traffic": "t-open",
-         "chips": 1, "why": "tiny open loop"},
+         "chips": chips, "why": "tiny open loop"},
         {"name": "tiny.closed", "config": "tiny", "traffic": "t-closed",
-         "chips": 1, "why": "tiny closed loop"}]
+         "chips": chips, "why": "tiny closed loop"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
             opened = any(w.endswith("open") for w in m["workloads"])

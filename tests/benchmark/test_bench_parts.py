@@ -5,6 +5,7 @@ from __future__ import annotations
 import asyncio
 import json
 import math
+import os
 import shutil
 import time
 from collections import Counter
@@ -17,6 +18,7 @@ from benchmarks.chip import run_cell
 from benchmarks.chip.catalog import HERE, Catalog
 
 CATALOG = Catalog()
+FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures")
 
 
 def test_adc_counts_by_hand():
@@ -46,7 +48,7 @@ def test_roofline_is_the_larger_bound_over_kernel_time():
     peaks = CATALOG.json(".", "peaks")["devices"]["TPU v5 lite"]
     tr = Trace(ops=[("quantized_maxsim_pallas.1", 0, 10**6),
                     ("copy.2", 10**6, 2 * 10**6)])
-    run = SimpleNamespace(trace=tr, trace_bounds=(0, 2 * 10**6),
+    run = SimpleNamespace(trace=tr, traces=[tr],
                           catalog=CATALOG, config=cfg, pages=65536,
                           peaks=peaks, traced_spans=[(0, 1, 1, 1)])
     ops, nbytes = CATALOG.module("kernels", "adc").search_counts(
@@ -55,6 +57,41 @@ def test_roofline_is_the_larger_bound_over_kernel_time():
     assert readers.roofline_pct(run, "adc") == pytest.approx(
         100 * least / 1e-3)
     assert readers.roofline_pct(run, "hamming") is None  # nothing to read
+
+
+def test_roofline_and_busy_time_over_every_chip():
+    """On the recorded chip fixture, one trace reads what the one-chip
+    harness read; four chips' traces of the same work read a quarter of
+    the roofline (the cell's work over four chips' kernel time), and
+    four equal busy times."""
+    from benchmarks.chip import readers
+    from benchmarks.chip.spantrace import ScopedTrace
+    from benchmarks.chip.trace import top_ops
+
+    tr = ScopedTrace.from_json(os.path.join(FIXTURE,
+                                            "v5e_scoped_search.json.gz"))
+    lo, hi = tr.window()
+
+    def run(traces):
+        return SimpleNamespace(
+            trace=traces[0], traces=traces, catalog=CATALOG,
+            config=CATALOG.json("configs", "colpali-hpc"), pages=1 << 18,
+            peaks=CATALOG.json(".", "peaks")["devices"]["TPU v5 lite"],
+            traced_spans=[(0.0, 0.1, 1, 1)])
+
+    one, four = run([tr]), run([tr] * 4)
+    assert readers.roofline_pct(one, "adc") == 0.17098157118412502
+    assert readers.busy_s(one) == 0.124521741
+    assert readers.busy_s_per_chip(one) == [0.124521741]
+    assert readers.roofline_pct(four, "adc") == pytest.approx(
+        0.17098157118412502 / 4, rel=1e-15)
+    assert readers.busy_s(four) == 0.124521741
+    assert readers.busy_s_per_chip(four) == [0.124521741] * 4
+    assert top_ops([tr] * 4, lo, hi, 3) == [
+        [name, pytest.approx(4 * s, rel=1e-15)]
+        for name, s in top_ops([tr], lo, hi, 3)]
+    assert top_ops([tr], lo, hi, 1) == [["quantized_maxsim_pallas_scan",
+                                         0.115120826]]
 
 
 def test_open_loop_schedule_is_a_poisson_sample_of_the_mix():
@@ -125,8 +162,9 @@ def test_closed_loop_keeps_each_client_to_one_request():
 
 
 def test_parts_are_found_by_name_in_new_files(tmp_path):
-    """A cell, configuration, mix, driver, kernel and metric added as
-    files, with an entry in BENCHMARK.json, need no edit elsewhere."""
+    """A cell, configuration, system, mix, driver, kernel and metric
+    added as files, with an entry in BENCHMARK.json, need no edit
+    elsewhere."""
     root = tmp_path / "benchmarks" / "chip"
     shutil.copytree(HERE, root, ignore=shutil.ignore_patterns(
         "__pycache__"))
@@ -135,7 +173,11 @@ def test_parts_are_found_by_name_in_new_files(tmp_path):
                              "file": "benchmarks/chip/configs/new.json",
                              "reduced": [], "why": "added by files"})
     (root / "configs" / "new.json").write_text(json.dumps(
-        dict(CATALOG.config("colpali-hpc"), name="new-config")))
+        dict(CATALOG.config("colpali-hpc"), name="new-config",
+             system="new_system")))
+    (root / "systems" / "new_system.py").write_text(
+        "CHIPS = (4,)\n\ndef build(*a):\n    return None, None\n\n"
+        "def compile(*a, **k):\n    return {}\n")
     bench["workloads"].append({"name": "new.cell", "config": "new-config",
                                "traffic": "new-mix", "chips": 1,
                                "why": "added by files"})
@@ -166,6 +208,10 @@ def test_parts_are_found_by_name_in_new_files(tmp_path):
     assert cat.module("traffic", cell["mix"]["driver"]).drive
     assert cat.module("metrics", "new.metric").read(None) == 1.5
     assert cat.module("kernels", "new_kernel").search_counts(0, 0, 0)
+    assert cat.system(cell["config"]).CHIPS == (4,)
+    # a configuration that names no system is served on one chip
+    one = cat.system(cat.config("colpali-hpc"))
+    assert one.CHIPS == (1,) and one.build and one.compile
 
 
 def test_every_cell_and_metric_has_its_files():
@@ -198,12 +244,22 @@ def test_runner_refuses_cpu_and_unknown_chips(capsys):
     assert run_cell.require_devices(1, peaks, [tpu]) == [tpu]
     with pytest.raises(run_cell.RunRefused, match="asks for 4"):
         run_cell.require_devices(4, peaks, [tpu])
-    # four chips are there, but the harness serves on one
-    with pytest.raises(run_cell.RunRefused, match="serves on one chip"):
+    with pytest.raises(run_cell.RunRefused, match="JAX found 1"):
+        run_cell.require_devices(4, peaks, [tpu], allowed=(1, 4))
+    # four chips are there, but the cell's system serves on one
+    with pytest.raises(run_cell.RunRefused, match=r"serves on \(1,\)"):
         run_cell.require_devices(4, peaks, [tpu] * 4)
+    # a cell takes one chip or four
+    with pytest.raises(run_cell.RunRefused, match="1 or 4"):
+        run_cell.require_devices(2, peaks, [tpu] * 4, allowed=(1, 2, 4))
+    assert run_cell.require_devices(4, peaks, [tpu] * 5,
+                                    allowed=(1, 4)) == [tpu] * 4
     other = SimpleNamespace(platform="tpu", device_kind="TPU v9 ultra")
     with pytest.raises(run_cell.RunRefused, match="no peaks"):
         run_cell.require_devices(1, peaks, [other])
+    with pytest.raises(run_cell.RunRefused, match="no peaks"):
+        run_cell.require_devices(4, peaks, [tpu] * 3 + [other],
+                                 allowed=(1, 4))
     rc = run_cell.main(["--workload", "colpali-hpc.scan-open", "--seed",
                         "1", "--seconds", "1", "--trace", "0"])
     out = capsys.readouterr()

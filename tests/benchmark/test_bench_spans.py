@@ -16,7 +16,7 @@ from benchmarks.chip import readers, run_cell, served, traced
 from benchmarks.chip.catalog import Catalog
 from benchmarks.chip.spantrace import (ScopedTrace, hlo_scopes,
                                       instruction, scope_of)
-from benchmarks.chip.trace import Trace
+from benchmarks.chip.trace import Trace, top_ops
 from repro.tracing import Span
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures")
@@ -255,8 +255,7 @@ def test_existing_metrics_read_the_same_on_a_scoped_trace():
         tr = cls.from_json(path)
         lo, hi = tr.window()
         run = SimpleNamespace(
-            trace=tr, trace_bounds=(min(o[1] for o in tr.ops),
-                                    max(o[2] for o in tr.ops)),
+            trace=tr, traces=[tr],
             catalog=CATALOG, config=cfg, pages=1 << 18, peaks=peaks,
             traced_spans=[(0.0, 0.1, 1, 1)], records=[
                 {"result": 1, "done": 0.5, "due": 0.0}], t1=1.0)
@@ -264,7 +263,7 @@ def test_existing_metrics_read_the_same_on_a_scoped_trace():
                   for m in CATALOG.benchmark["per_layer"]
                   if m["source"] == "device_trace"}
         values["busy_s"] = readers.busy_s(run)
-        values["top_ops"] = tr.top_ops(lo, hi)
+        values["top_ops"] = top_ops([tr], lo, hi)
         values["idle_gaps"] = tr.idle_gaps(lo, hi)
         got.append(values)
     assert got[0] == got[1]
@@ -281,9 +280,10 @@ def test_tiny_served_run_with_the_tracer(tmp_path, cell):
     nothing (no device plane)."""
     cat = tiny_catalog(tmp_path)
     seed = 2**33 + 7
-    c = run_cell.Cell(cat, cell, seed, annotate=True)
+    c = run_cell.Cell(cat, cell, seed, annotate=True,
+                      devices=jax.devices()[:1])
     hlo = traced.program_scopes(c.search.compiled)
-    run = traced.traced_window(c, 1.0, seed, True, jax.devices()[0], hlo)
+    run = traced.traced_window(c, 1.0, seed, True, hlo)
     recs = run.serve_records
     answered = [r for r in run.records if "result" in r]
     assert answered

@@ -7,7 +7,7 @@ import os
 
 import pytest
 
-from benchmarks.chip.trace import Trace, op_name
+from benchmarks.chip.trace import Trace, op_name, top_ops
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures")
 
@@ -39,8 +39,8 @@ def test_kernel_time_and_top_ops():
     assert tr.kernel_ns("quantized_maxsim_pallas", lo, hi) == 6 * 10**6
     assert tr.kernel_ns("hamming_maxsim_pallas", lo, hi) == 0
     # the while's self time excludes the copy it encloses
-    assert tr.top_ops(lo, hi) == [["quantized_maxsim_pallas", 0.006],
-                                  ["while", 0.001], ["copy", 0.001]]
+    assert top_ops([tr], lo, hi) == [["quantized_maxsim_pallas", 0.006],
+                                     ["while", 0.001], ["copy", 0.001]]
 
 
 def test_op_names_from_hlo_text():
@@ -81,9 +81,9 @@ def test_recorded_chip_trace():
     assert tr.kernel_ns("quantized_maxsim_pallas", lo, hi) == 115_270_042
     assert sum(1 for o in tr.ops
                if "quantized_maxsim_pallas" in o[0]) == 1025
-    assert tr.top_ops(lo, hi, 2) == [["quantized_maxsim_pallas",
-                                      0.115120775],
-                                     ["sort", 0.003910897]]
+    assert top_ops([tr], lo, hi, 2) == [["quantized_maxsim_pallas",
+                                         0.115120775],
+                                        ["sort", 0.003910897]]
     gaps = tr.idle_gaps(lo, hi, 2)
     assert [g[0] for g in gaps] == ["bench.window", "bench.window"]
     assert sum(g[1] for g in gaps) == pytest.approx(
