@@ -36,6 +36,7 @@ import inspect
 import os
 import warnings
 import zlib
+from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -45,6 +46,7 @@ import numpy as np
 from repro.core import index as index_mod
 from repro.core import pruning
 from repro.core import quantization as quant
+from repro.dist.sharding import Sharder, is_logical_spec
 from repro.retrieval.config import HPCConfig
 
 Array = jax.Array
@@ -331,6 +333,13 @@ def encode_delta(codebook: Array, delta: Corpus, cfg: HPCConfig
     return codes_full, codes, mask
 
 
+@partial(jax.jit, donate_argnums=0)
+def _write_rows(buffers, rows, at):
+    """`rows` written into `buffers` from row `at` on, in place."""
+    return [jax.lax.dynamic_update_slice_in_dim(b, r.astype(b.dtype), at, 0)
+            for b, r in zip(buffers, rows)]
+
+
 # ---------------------------------------------------------------------------
 # Backend base class
 # ---------------------------------------------------------------------------
@@ -589,6 +598,96 @@ class IndexBackend:
                 rc_rows.astype(state.rerank_codes.dtype)),
             rerank_mask=state.rerank_mask.at[ids_j].set(
                 rm_rows.astype(state.rerank_mask.dtype)))
+
+    def build_sharded(self, key: Array, chunks, cfg: HPCConfig, *,
+                      n_docs: int, mesh) -> RetrieverState:
+        """Streaming sharded build; see `Retriever.build_sharded`."""
+        shd = Sharder(mesh)
+        share = shd.named(("corpus",), (n_docs,))
+        rows_of = {d: (idx[0].start or 0, idx[0].stop or n_docs)
+                   for d, idx in share.devices_indices_map(
+                       (n_docs,)).items()}
+        if len(set(rows_of.values())) != len(rows_of):
+            raise ValueError(
+                f"{n_docs} docs do not split over the corpus axes of a "
+                f"{mesh.devices.size}-device mesh")
+
+        def owner(offset: int, n: int):
+            for d, (lo, hi) in rows_of.items():
+                if lo <= offset and offset + n <= hi:
+                    return d, offset - lo
+            raise ValueError(f"chunk [{offset}, {offset + n}) is not inside "
+                             f"one device's share of {n_docs} docs")
+
+        it = iter(chunks)
+        offset, first = next(it)
+        if offset != 0:
+            raise ValueError(f"the first chunk is at offset {offset}; the "
+                             "codebook is fit on the chunk at offset 0")
+        dev, _ = owner(0, first.embeddings.shape[0])
+        # the one-chip build of the first chunk: the codebook, and its rows
+        state0 = self.build(key, jax.device_put(first, dev), cfg)
+        specs = jax.tree.leaves(self.shard_specs(state0),
+                                is_leaf=is_logical_spec)
+        leaves0, treedef = jax.tree.flatten(state0)
+        is_row = [bool(s) and s[0] == "corpus" for s in specs]
+        # every other chunk is encoded against the finished fit
+        jax.block_until_ready(state0)
+        local = {d: [jnp.zeros((hi - lo,) + x.shape[1:], x.dtype, device=d)
+                     if r else jax.device_put(x, d)
+                     for x, r in zip(leaves0, is_row)]
+                 for d, (lo, hi) in rows_of.items()}
+        written = np.zeros(n_docs, bool)
+        in_flight = []
+
+        def write(d, at, rows):
+            bufs = [x for x, r in zip(local[d], is_row) if r]
+            new = iter(_write_rows(bufs, rows, jax.device_put(
+                jnp.int32(at), d)))
+            local[d] = [next(new) if r else x
+                        for x, r in zip(local[d], is_row)]
+            # at most one chunk a device queued behind the one it runs
+            in_flight.append(rows[-1])
+            if len(in_flight) > len(rows_of):
+                jax.block_until_ready(in_flight.pop(0))
+
+        n = first.embeddings.shape[0]
+        written[:n] = True
+        write(dev, 0, [x for x, r in zip(leaves0, is_row) if r])
+        del first, state0
+        for offset, delta in it:
+            n = delta.embeddings.shape[0]
+            dev, at = owner(offset, n)
+            if written[offset:offset + n].any():
+                raise ValueError(f"chunk [{offset}, {offset + n}) overlaps "
+                                 "one already written")
+            written[offset:offset + n] = True
+            delta = jax.device_put(delta, dev)
+            st = jax.tree.unflatten(treedef, local[dev])
+            enc = self._encode_delta(st, delta, cfg)
+            ids = jax.device_put(
+                np.arange(offset, offset + n, dtype=np.int32), dev)
+            payload, _ = self._delta_segment(st, None, enc, delta, cfg,
+                                             ids)
+            s = st.backend_state
+            rc, rm = self._rerank_delta_rows(enc, delta)
+            rows = st._replace(
+                backend_state=(dataclasses.replace(s, index=payload)
+                               if self._is_wrapper(s) else payload),
+                rerank_codes=rc, rerank_mask=rm)
+            del st
+            write(dev, at, [x[:n] for x, r in zip(
+                jax.tree.leaves(rows), is_row) if r])
+        if not written.all():
+            raise ValueError(f"the chunks cover {int(written.sum())} of "
+                             f"{n_docs} docs")
+        out = []
+        for j, (x, spec) in enumerate(zip(leaves0, specs)):
+            shape = (n_docs,) + x.shape[1:] if is_row[j] else x.shape
+            out.append(jax.make_array_from_single_device_arrays(
+                shape, shd.named(tuple(spec), shape),
+                [local[d][j] for d in rows_of]))
+        return jax.tree.unflatten(treedef, out)
 
     def delete(self, state: RetrieverState, doc_ids) -> RetrieverState:
         """Tombstone documents by global id. O(total slots) host work, no

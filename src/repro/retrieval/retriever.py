@@ -57,6 +57,44 @@ class Retriever:
             return self.backend.build(key, corpus, self.cfg)
         return self.backend.build(key, corpus, self.cfg, mesh=mesh)
 
+    def build_sharded(self, key: Array, chunks, *, n_docs: int,
+                      mesh: Mesh) -> RetrieverState:
+        """Streaming build of a corpus no one device holds, straight into
+        its shares on `mesh`.
+
+        `chunks` yields `(offset, Corpus)`: the docs with global ids
+        `offset, offset + 1, ...`, in any order, the chunk at offset 0
+        first, each inside one device's share (`shard_specs` over `mesh`),
+        together covering ids `[0, n_docs)` once. The codebook is fit on
+        the first chunk by `build` with `key`, exactly as a one-device
+        build on that chunk fits it, and replicated. Every other chunk is
+        coded and pruned against it by the path `add` takes, on the device
+        that owns its rows, and written into that device's preallocated
+        share in place. The result is the monolithic state `shard` would
+        place: row for row the codes, masks and rerank rows of `build` on
+        the first chunk, `add` for each further chunk in offset order and
+        `compact`.
+
+        A device holds its share of the index, the chunk it encodes and at
+        most one more queued behind it (the build waits for a device's
+        chunk before it takes the one after the next), and the first
+        device also the codebook fit. Chunks dealt to the devices in turn
+        are encoded on all of them at once. Search the result with
+        `search_sharded`. Needs a backend whose `search` is an exhaustive
+        scan (`IndexBackend.exhaustive`).
+        """
+        self._require_exhaustive("build_sharded")
+        return self.backend.build_sharded(key, chunks, self.cfg,
+                                          n_docs=n_docs, mesh=mesh)
+
+    def _require_exhaustive(self, what: str) -> None:
+        backend = self.backend
+        if not backend.exhaustive:
+            raise NotImplementedError(
+                f"backend {backend.name!r} routes queries through its own "
+                f"structure; {what} needs an exhaustive scan "
+                "(flat, float_flat, hamming)")
+
     # -- online -------------------------------------------------------------
 
     def _prune_query(self, query: Query) -> Query:
@@ -104,21 +142,20 @@ class Retriever:
         """`search` over a state placed on `mesh` by `shard`.
 
         Each device scans only the documents it holds and keeps its own
-        top candidates; the candidates merge over the mesh (an all-gather
-        of (B, k) rows and a top-k), and the rerank scores each merged
-        candidate on the device that holds its unpruned row. The whole
-        search runs under `jax.shard_map`: the TPU compiler cannot split
-        a Pallas kernel across devices by itself. Returns what `search`
-        returns for the same state on one device, up to the order of
-        equal scores. Needs a backend whose `search` is an exhaustive
-        scan (`IndexBackend.exhaustive`).
+        top candidates; the candidates merge over the mesh, an all-gather
+        of (B, k) rows and a top-k under scope `search.shard_merge`. For
+        the rerank every device holds all the merged candidates and scores
+        those whose unpruned rows it holds, the others masked out, so its
+        gather reads all `n_cand` rows on every device; a second
+        `search.shard_merge` keeps the best k. The whole search runs under
+        `jax.shard_map`: the TPU compiler cannot split a Pallas kernel
+        across devices by itself. Returns what `search` returns for the
+        same state on one device, up to the order of equal scores. Needs a
+        backend whose `search` is an exhaustive scan
+        (`IndexBackend.exhaustive`).
         """
         cfg, backend = self.cfg, self.backend
-        if not backend.exhaustive:
-            raise NotImplementedError(
-                f"backend {backend.name!r} routes queries through its own "
-                "structure; search_sharded needs an exhaustive scan "
-                "(flat, float_flat, hamming)")
+        self._require_exhaustive("search_sharded")
         shd = Sharder(mesh)
         specs = jax.tree.map(
             lambda spec, leaf: shd.resolve(tuple(spec), jnp.shape(leaf)),
@@ -136,11 +173,13 @@ class Retriever:
             # one gather over all corpus axes lays the devices' rows out in
             # doc order, so equal scores still resolve to the lower id (a
             # gather per axis would put the last axis outermost)
-            scores = jax.lax.all_gather(scores, axes, axis=1, tiled=True)
-            ids = jax.lax.all_gather(ids, axes, axis=1, tiled=True)
-            # every device contributes kk rows, so kk <= the gathered width
-            top, pos = jax.lax.top_k(scores, kk)  # noqa: JAX04
-            return top, jnp.take_along_axis(ids, pos, axis=1)
+            with jax.named_scope("search.shard_merge"):
+                scores = jax.lax.all_gather(scores, axes, axis=1,
+                                            tiled=True)
+                ids = jax.lax.all_gather(ids, axes, axis=1, tiled=True)
+                # every device contributes kk rows, so kk <= the width
+                top, pos = jax.lax.top_k(scores, kk)  # noqa: JAX04
+                return top, jnp.take_along_axis(ids, pos, axis=1)
 
         def local(st, q):
             with jax.named_scope("search.prune"):
